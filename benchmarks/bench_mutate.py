@@ -42,7 +42,7 @@ import json, time
 import numpy as np
 from repro.configs import graph_workloads
 from repro.core import GraphEngine, localops, partition_graph
-from repro.core.compat import runtime_fingerprint
+from repro.core.runtime import runtime_fingerprint
 from repro.graphs import generate_edges
 from repro.launch.mesh import make_graph_mesh
 from repro.roofline import analysis as RA

@@ -68,7 +68,7 @@ _CELL_CODE = r"""
 import json
 from repro.configs import graph_workloads
 from repro.core import GraphEngine, localops, partition_graph
-from repro.core.compat import runtime_fingerprint
+from repro.core.runtime import runtime_fingerprint
 from repro.graphs import generate_edges
 from repro.launch.mesh import make_graph_mesh
 from repro.serve import GraphServer, Query, make_key

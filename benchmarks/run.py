@@ -26,7 +26,7 @@ def runtime_meta() -> dict:
     must set XLA_FLAGS before its first jax import).  Recorded in the
     bench meta so benchmarks/compare.py can tell environment drift
     (jax upgrade, CPU-vs-TPU move) from real regressions."""
-    code = ("import json; from repro.core.compat import "
+    code = ("import json; from repro.core.runtime import "
             "runtime_fingerprint; print(json.dumps(runtime_fingerprint()))")
     env = dict(os.environ)
     env["PYTHONPATH"] = str(REPO_ROOT / "src")

@@ -15,8 +15,9 @@
 # fast lane (selected as "tier1 or not slow", so tier1 wins even if a
 # suite someday also gets marked slow): the kernel-interpret parity
 # suites (tests/test_kernels_{spmv,frontier}.py) carry it because the
-# localops dispatch layer routes production hot loops through those
-# kernels.
+# localops dispatch layer's `kernel` mode drives those kernels.  The
+# production hot loops take the blocked-ELL gather path on every
+# backend: Mosaic does not lower either kernel for a TPU yet.
 #
 # The fast benches write BENCH_graph.json (direct launches — the bfs
 # and pagerank figures emit bsp-vs-async row pairs, each row carrying

@@ -34,7 +34,6 @@ import jax.numpy as jnp
 
 from repro.core import localops, registry
 from repro.core import faults as faults_mod
-from repro.core.compat import shard_map
 from repro.core.graph import GraphShards
 from repro.core.superstep import run_program, run_program_batched
 from repro.obs import telemetry as obs_telemetry
@@ -290,7 +289,7 @@ class GraphEngine:
             + ((P(),) if telemetry else ())
         in_specs = (_graph_specs(g, self.layout),) + tuple(
             P() if kind == "scalar" else P("parts", None) for kind in kinds)
-        jitted = jax.jit(shard_map(
+        jitted = jax.jit(jax.shard_map(
             fn, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=False))
 

@@ -19,8 +19,9 @@ Paper mapping (SS4.1):
 The per-level LOCAL edge work routes through ``core/localops.py``: the
 push-combine is ``scatter_combine`` over the blocked-ELL ``ell_dst``
 structure and owner-side parent derivation is ``frontier_pull`` over
-``ell_in`` (the Pallas BFS-pull kernel on TPU) - no serialized scatters
-on any backend.  The push candidate exchange is the packed-uint32
+``ell_in`` (a dense blocked-ELL gather; the Pallas BFS-pull kernel only
+under ``REPRO_LOCALOPS=kernel``) - no serialized scatters on any
+backend.  The push candidate exchange is the packed-uint32
 ``exchange_or`` of ``core/partitioned.py``.
 
 Both are expressed as :class:`~repro.core.superstep.SuperstepProgram`

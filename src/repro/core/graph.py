@@ -52,6 +52,7 @@ Four instances are built (``GraphShards.ell_meta``):
 
 from __future__ import annotations
 
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -167,15 +168,28 @@ def make_scatter_patch(mesh):
     pre-mutation buffers — that copy-on-write is the snapshot-epoch
     isolation guarantee — while only the small patch lists ever cross
     host->device (never the full shards)."""
-    from repro.core.compat import shard_map
-
     def _patch(arr, slots, vals):
         return arr[0].at[slots[0]].set(vals[0], mode="drop")[None]
 
     pspec = jax.sharding.PartitionSpec("parts", None)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         _patch, mesh=mesh, in_specs=(pspec, pspec, pspec),
         out_specs=pspec, check_vma=False))
+
+
+def _stable_argsort(keys: np.ndarray) -> np.ndarray:
+    """``np.argsort(keys, kind="stable")`` for non-negative int keys.
+
+    Sorts unique composite keys ``key * m + position`` instead: the same
+    order, several times faster at 10^7+ entries, which is what keeps
+    the host build of a paper-scale graph in tens of seconds."""
+    m = keys.size
+    comp = keys.astype(np.int64)
+    comp *= m
+    comp += np.arange(m, dtype=np.int64)
+    comp.sort()
+    comp %= m
+    return comp
 
 
 def build_ell(name: str, row_ids: np.ndarray, values: np.ndarray,
@@ -201,7 +215,7 @@ def build_ell(name: str, row_ids: np.ndarray, values: np.ndarray,
         valid = row_ids[p] >= 0
         counts[p] = np.bincount(row_ids[p][valid].astype(np.int64),
                                 minlength=n_rows)
-        perms[p] = np.argsort(-counts[p], kind="stable")
+        perms[p] = _stable_argsort(counts[p].max() - counts[p])
 
     # SPMD-uniform block widths: max over partitions, rounded to lanes.
     widths_pp = np.take_along_axis(counts, perms, axis=1) \
@@ -211,27 +225,32 @@ def build_ell(name: str, row_ids: np.ndarray, values: np.ndarray,
     row_base, row_width = _ell_row_base(buckets)
     slots = int(sum(r * k for r, k in buckets))
 
-    idx = np.full((parts, max(slots, 1)), sentinel, np.int64)
-    inv = np.zeros((parts, n_rows), np.int64)
+    # int32 like the device arrays; the per-entry temporaries are freed
+    # as soon as they are used, since four builds run side by side
+    idx = np.full((parts, max(slots, 1)), sentinel, np.int32)
+    inv = np.zeros((parts, n_rows), np.int32)
     for p in range(parts):
         inv[p, perms[p]] = np.arange(n_rows)
         valid = row_ids[p] >= 0
-        rows_v = row_ids[p][valid].astype(np.int64)
-        vals_v = values[p][valid].astype(np.int64)
-        order = np.argsort(rows_v, kind="stable")
-        rows_s, vals_s = rows_v[order], vals_v[order]
+        rows_s = row_ids[p][valid]
+        order = _stable_argsort(rows_s)
+        vals_s = values[p][valid][order]
+        rows_s = rows_s[order]
+        del valid, order
         first = np.concatenate([[0], np.cumsum(counts[p])[:-1]])
         rank = np.arange(rows_s.size) - first[rows_s]
         q = inv[p, rows_s]                       # ELL row of each entry
+        del rows_s
         assert (rank < row_width[q]).all(), name
         idx[p, row_base[q] + rank] = vals_s
+        del rank, q, vals_s
 
     meta = EllMeta(name=name, n_rows=n_rows, buckets=buckets, slots=slots,
                    sentinel=sentinel,
                    device_suffixes=tuple(device_suffixes))
     arrays = {
-        f"{name}_idx": idx[:, :max(slots, 1)].astype(np.int32),
-        f"{name}_inv": inv.astype(np.int32),
+        f"{name}_idx": idx,
+        f"{name}_inv": inv,
     }
     if "perm" in device_suffixes:
         # only materialized when it ships (frontier_pull's row gather);
@@ -350,7 +369,7 @@ def _group_edges(key: np.ndarray, other: np.ndarray, parts: int,
                  n_local: int, e_max: int, n_sentinel: int, key_local: bool):
     """Group (key, other) pairs by key-owner partition into padded (P, E)."""
     owner = key // n_local
-    order = np.argsort(owner, kind="stable")
+    order = _stable_argsort(owner)
     key_s, other_s, owner_s = key[order], other[order], owner[order]
     counts = np.bincount(owner_s, minlength=parts)
     starts = np.concatenate([[0], np.cumsum(counts)[:-1]])
@@ -388,11 +407,16 @@ def _build_graph_ells(g: "GraphShards") -> None:
          np.where(in_valid, g.in_src_global, -1), pos,
          n, e_max, ("idx", "inv")),
     ]
-    for name, rows, vals, n_rows, sentinel, sufs in specs:
-        meta, arrays = build_ell(name, rows, vals, n_rows, sentinel,
-                                 device_suffixes=sufs)
-        g.ell_meta[name] = meta
-        g.ell_arrays.update(arrays)
+    # the four builds are independent NumPy work that releases the GIL:
+    # building them side by side roughly halves a paper-scale build
+    with ThreadPoolExecutor(max_workers=len(specs)) as pool:
+        built = [pool.submit(build_ell, name, rows, vals, n_rows, sentinel,
+                             device_suffixes=sufs)
+                 for name, rows, vals, n_rows, sentinel, sufs in specs]
+        for fut in built:
+            meta, arrays = fut.result()
+            g.ell_meta[meta.name] = meta
+            g.ell_arrays.update(arrays)
 
 
 def partition_graph(edges: np.ndarray, n_orig: int, parts: int,

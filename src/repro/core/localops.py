@@ -34,14 +34,24 @@ Each primitive has THREE implementations, selected at trace time:
                 pull kernel; non-kernelizable ops stay on the ell path).
 
 Mode resolution: the ``REPRO_LOCALOPS`` env var (or :func:`set_mode`)
-picks ``auto`` (default: pallas on TPU, ell elsewhere), ``ref``, or
-``kernel`` (force the Pallas kernels, interpreted off-TPU).  When the
-graph dict carries no ELL arrays (``--layout coo``), every call falls
-back to ``ref`` regardless of mode.
+picks ``auto`` (default: ell on every backend, the TPU included),
+``ref``, or ``kernel`` (the Pallas kernels, interpreted off-TPU).
+``auto`` never picks the kernels because Mosaic refuses both for a TPU
+("Only 2D gather is supported": each gathers from a whole-array 1-D
+VMEM block with a 2-D index); ``kernel`` on a TPU compiles them
+natively and lets that error propagate.  When the graph dict carries
+no ELL arrays (``--layout coo``), every call falls back to ``ref``
+regardless of mode.
 
 All functions are pure per-partition compute (no collectives), callable
 inside or outside ``shard_map``, and vmap cleanly for batched
-multi-source programs.
+multi-source programs.  The ell path gathers each bucket slot-major, as
+a ``(k, rows)`` view with the rows minor, and every gather of a
+per-query field goes through :func:`_take`, whose batching rule keeps
+the lane axis leading.  vmap's own rule for a batched operand gathered
+by shared indices would put the lane axis minor, which a TPU pads to
+128 lanes: batch=8 bfs/fast on urand22 then asked for ~60 GB of a
+16 GB v5e.
 """
 
 from __future__ import annotations
@@ -90,12 +100,15 @@ def get_mode() -> str:
 
 
 def resolve(mode: str | None = None, backend: str | None = None) -> str:
-    """Concrete implementation a call would take: ref | ell | pallas."""
+    """Concrete implementation a call would take: ref | ell | pallas.
+
+    ``backend`` lets a caller ask about a platform it is not running on
+    (``resolve("auto", backend="tpu")``); no backend changes the answer,
+    since ``auto`` is ``ell`` on the TPU too (see the module doc)."""
     mode = mode or get_mode()
-    backend = backend or jax.default_backend()
     if mode == "ref":
         return "ref"
-    if mode == "kernel" or backend == "tpu":
+    if mode == "kernel":
         return "pallas"
     return "ell"
 
@@ -110,6 +123,45 @@ def _interpret() -> bool:
 
 def _has_ell(g: dict, ell: EllMeta) -> bool:
     return f"{ell.name}_idx" in g
+
+
+# largest flat offset a batched :func:`_take` adds to its int32 indices
+_FLAT_LIMIT = 2 ** 31 - 1
+
+
+@jax.custom_batching.custom_vmap
+def _take(x, idx):
+    """``x[idx]``: gather a per-query 1-D field by graph indices."""
+    return x[idx]
+
+
+@_take.def_vmap
+def _take_vmap(axis_size, in_batched, x, idx):
+    """Batched ``x`` (B, N): gather from the flattened (B*N,) field with
+    lane offsets added to the indices, so the output is (B, *idx.shape)
+    with ``idx``'s minor axis minor (vmap's default rule takes (B, 1)
+    slices and lays the lane axis out minor).  Lanes go in chunks whose
+    flat offsets fit int32."""
+    x_batched, idx_batched = in_batched
+    if not x_batched:
+        return x[idx], idx_batched
+    n = x.shape[1]
+    lane_ndim = idx.ndim - 1 if idx_batched else idx.ndim
+    per = max(1, _FLAT_LIMIT // n)
+    chunks = []
+    for b0 in range(0, axis_size, per):
+        nb = min(per, axis_size - b0)
+        off = (jnp.arange(nb, dtype=jnp.int32) * n).reshape(
+            (nb,) + (1,) * lane_ndim)
+        lane_idx = idx[b0:b0 + nb] if idx_batched else idx
+        chunks.append(x[b0:b0 + nb].reshape(-1)[lane_idx + off])
+    return jnp.concatenate(chunks) if len(chunks) > 1 else chunks[0], True
+
+
+def _test_bit(packed, idx):
+    """:func:`~repro.core.partitioned.test_bit` through :func:`_take`."""
+    word = _take(packed, idx >> 5)
+    return (word >> (idx & 31).astype(jnp.uint32)) & 1
 
 
 def _buckets(ell: EllMeta, flat):
@@ -154,14 +206,16 @@ def spmv_pull(g: dict, ell: EllMeta, x, *, mode: str | None = None):
         if k == 0:
             outs.append(jnp.zeros((rows,), jnp.float32))
             continue
-        vmask = blk != ell.sentinel
         if use_pallas:
             from repro.kernels.spmv.kernel import spmv_ell
-            outs.append(spmv_ell(blk, vmask.astype(jnp.float32), xk,
+            vmask = (blk != ell.sentinel).astype(jnp.float32)
+            outs.append(spmv_ell(blk, vmask, xk,
                                  row_block=128, interpret=_interpret()))
         else:
-            outs.append(jnp.where(vmask, xk[blk], 0.0).sum(axis=1))
-    return jnp.concatenate(outs)[inv]
+            cols = blk.T                        # (k, rows), rows minor
+            outs.append(jnp.where(cols != ell.sentinel, _take(xk, cols),
+                                  0.0).sum(axis=0))
+    return _take(jnp.concatenate(outs), inv)
 
 
 # ---------------------------------------------------------------------------
@@ -192,7 +246,7 @@ def frontier_pull(g: dict, ell: EllMeta, bits, unvisited, *,
     idx = g[f"{ell.name}_idx"]
     inv = g[f"{ell.name}_inv"]
     perm = g[f"{ell.name}_perm"]
-    unv_ell = unvisited[perm]
+    unv_ell = _take(unvisited, perm)
     # sentinel n indexes one word past the bitmap: append a zero guard
     bits_g = jnp.concatenate([bits, jnp.zeros((1,), jnp.uint32)])
     use_pallas = _use_pallas(mode)
@@ -207,10 +261,11 @@ def frontier_pull(g: dict, ell: EllMeta, bits, unvisited, *,
             outs.append(bfs_pull(blk, bits_g, unv_b.astype(jnp.int32),
                                  row_block=128, interpret=_interpret()))
         else:
-            hit = test_bit(bits_g, blk) == 1
-            cand = jnp.where(hit, blk, INT_INF).min(axis=1)
+            cols = blk.T
+            hit = _test_bit(bits_g, cols) == 1
+            cand = jnp.where(hit, cols, INT_INF).min(axis=0)
             outs.append(jnp.where(unv_b, cand, INT_INF))
-    return jnp.concatenate(outs)[inv]
+    return _take(jnp.concatenate(outs), inv)
 
 
 # ---------------------------------------------------------------------------
@@ -246,7 +301,7 @@ def pull_min_eq(g: dict, ell: EllMeta, xg, target, *,
     idx = g[f"{ell.name}_idx"]
     inv = g[f"{ell.name}_inv"]
     perm = g[f"{ell.name}_perm"]
-    tgt_ell = target[perm]
+    tgt_ell = _take(target, perm)
     # sentinel n indexes one slot past xg: append a guard no real target
     # equals (INT_INF; targets are levels < n or INT_INF - 1 for
     # unreached rows)
@@ -256,20 +311,22 @@ def pull_min_eq(g: dict, ell: EllMeta, xg, target, *,
         if k == 0:
             outs.append(jnp.full((rows,), INT_INF, jnp.int32))
             continue
-        hit = xg_g[blk] == tgt_ell[r0:r0 + rows][:, None]
-        outs.append(jnp.where(hit, blk, INT_INF).min(axis=1))
-    return jnp.concatenate(outs)[inv]
+        cols = blk.T
+        hit = _take(xg_g, cols) == tgt_ell[r0:r0 + rows][None, :]
+        outs.append(jnp.where(hit, cols, INT_INF).min(axis=0))
+    return _take(jnp.concatenate(outs), inv)
 
 
 # ---------------------------------------------------------------------------
 # scatter_combine
 # ---------------------------------------------------------------------------
 
+# reduce a slot-major (k, rows) gather over its slots
 _REDUCERS = {
-    "add": lambda a: a.sum(axis=1),
-    "min": lambda a: a.min(axis=1),
-    "max": lambda a: a.max(axis=1),
-    "or": lambda a: a.any(axis=1),
+    "add": lambda a: a.sum(axis=0),
+    "min": lambda a: a.min(axis=0),
+    "max": lambda a: a.max(axis=0),
+    "or": lambda a: a.any(axis=0),
 }
 
 
@@ -318,5 +375,5 @@ def scatter_combine(g: dict, ell: EllMeta, vals, op: str, *, identity,
             outs.append(spmv_ell(blk, vmask, vpad, row_block=128,
                                  interpret=_interpret()))
         else:
-            outs.append(_REDUCERS[op](vpad[blk]))
-    return jnp.concatenate(outs)[inv]
+            outs.append(_REDUCERS[op](_take(vpad, blk.T)))
+    return _take(jnp.concatenate(outs), inv)

@@ -24,9 +24,10 @@ loop.
 The local segment-sum is the SpMV hot spot; it routes through
 ``core/localops.py`` (``spmv_pull`` over the blocked-ELL in-neighbor
 lists for the pull variant, ``scatter_combine`` over ``ell_dst`` for the
-push variant): the Pallas SpMV kernel serves it on TPU, a dense
-per-bucket gather + row-sum everywhere else - the serialized COO
-scatter survives only as the ``REPRO_LOCALOPS=ref`` debug path.
+push variant): a dense per-bucket gather + row-sum on every backend
+(the Pallas SpMV kernel only under ``REPRO_LOCALOPS=kernel``) - the
+serialized COO scatter survives only as the ``REPRO_LOCALOPS=ref``
+debug path.
 """
 
 from __future__ import annotations
@@ -152,8 +153,7 @@ def pagerank_fast_program(shards, iters: int = 50,
         valid = dst < n
         contrib = _local_contrib(rank, g["out_degree"])
         # local segment-sum into a length-n accumulator (SpMV push);
-        # localops routes it to the Pallas spmv kernel on TPU and a
-        # dense blocked-ELL gather + row-sum elsewhere.
+        # localops routes it to a dense blocked-ELL gather + row-sum.
         acc = localops.scatter_combine(
             g, ell_dst, jnp.where(valid, contrib[srcl], 0.0), "add",
             identity=jnp.float32(0.0))

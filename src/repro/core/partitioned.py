@@ -46,7 +46,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import faults
-from repro.core.compat import axis_size
 from repro.obs import telemetry as obs_telemetry
 
 AXIS = "parts"
@@ -97,7 +96,7 @@ def exchange_sum(acc_global, axis_name: str = AXIS):
     Returns (n_local,) combined updates for the vertices THIS partition
     owns.  One reduce-scatter on the wire: (P-1)/P * n elements.
     """
-    parts = axis_size(axis_name)
+    parts = jax.lax.axis_size(axis_name)
     blocks = _tap("sum", acc_global.reshape(parts, -1), axis_name)
     return jax.lax.psum_scatter(blocks, axis_name, scatter_dimension=0,
                                 tiled=False).reshape(-1)
@@ -111,7 +110,7 @@ def exchange_or(mask_global, axis_name: str = AXIS):
     OR the P candidate rows - n/8 bytes total per partition instead of
     the 4n an int32-inflated psum_scatter pays (32x less wire).
     """
-    parts = axis_size(axis_name)
+    parts = jax.lax.axis_size(axis_name)
     n_local_words = mask_global.shape[0] // parts // 32
     packed = _tap(
         "or", pack_bits(mask_global).reshape(parts, n_local_words),
@@ -130,7 +129,7 @@ def exchange_min_int(val_global, axis_name: str = AXIS, big=None):
     all_to_all moves each partition's (P, n_local) proposal matrix so
     that owners receive P candidate rows; min over the row axis.
     """
-    parts = axis_size(axis_name)
+    parts = jax.lax.axis_size(axis_name)
     blocks = _tap("min", val_global.reshape(parts, -1), axis_name)
     rows = jax.lax.all_to_all(blocks.reshape(parts, 1, -1), axis_name,
                               split_axis=0,
@@ -177,7 +176,7 @@ def exchange_min_start(val_global, scalar, axis_name: str = AXIS):
     reducing.  ``scalar`` (the piggybacked halt count) is appended as a
     trailing payload column in the proposal dtype.  Returns the in-flight
     handle: ``(1, P, n_local + 1)`` received rows."""
-    parts = axis_size(axis_name)
+    parts = jax.lax.axis_size(axis_name)
     n_local = val_global.shape[0] // parts
     blocks = val_global.reshape(parts, n_local)
     payload = _tap("min", jnp.concatenate(
@@ -200,7 +199,7 @@ def exchange_sum_start(acc_global, scalar, axis_name: str = AXIS):
     the handle is already reduced data — the split still buys the driver
     a full local-compute window before :func:`exchange_sum_finish` reads
     it.  Returns the ``(n_local + 1,)`` handle."""
-    parts = axis_size(axis_name)
+    parts = jax.lax.axis_size(axis_name)
     n_local = acc_global.shape[0] // parts
     blocks = acc_global.reshape(parts, n_local)
     payload = _tap("sum", jnp.concatenate(
@@ -221,7 +220,7 @@ def exchange_or_start(mask_global, scalar, axis_name: str = AXIS):
     handle; finish with :func:`exchange_or_finish` (which needs the
     static ``n_local`` because the handle itself stays a pure array
     pytree a loop carry can hold)."""
-    parts = axis_size(axis_name)
+    parts = jax.lax.axis_size(axis_name)
     n_local_words = mask_global.shape[0] // parts // 32
     blocks = pack_bits(mask_global).reshape(parts, n_local_words)
     payload = _tap("or", jnp.concatenate(

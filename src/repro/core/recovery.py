@@ -49,7 +49,6 @@ import jax.numpy as jnp
 from repro.core import faults as faults_mod
 from repro.core import registry
 from repro.core.api import _graph_specs
-from repro.core.compat import shard_map
 from repro.core.superstep import PhasedProgram, carry_outputs, init_carry, \
     run_chunk
 from repro.obs import telemetry as obs_telemetry
@@ -162,7 +161,7 @@ class CheckpointRunner:
         return contextlib.nullcontext()
 
     def _jit(self, fn, in_specs):
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             fn, mesh=self.engine.mesh, in_specs=in_specs,
             out_specs=P("parts"), check_vma=False))
 

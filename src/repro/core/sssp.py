@@ -10,7 +10,7 @@ Expressed as a :class:`~repro.core.superstep.SuperstepProgram`: the
 ``prepare`` hook derives the loop-invariant weight array once, outside
 the driver loop, and rounds past convergence are no-ops (empty change
 set relaxes nothing), so the program is safe under ``static_iters`` and
-vmaps over batched roots for multi-source queries.
+maps over batched roots for multi-source queries.
 """
 
 from __future__ import annotations

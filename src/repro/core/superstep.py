@@ -36,7 +36,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import faults
-from repro.core.compat import axis_size
 from repro.core.partitioned import AXIS, psum_scalar
 from repro.obs import telemetry as obs_tel
 
@@ -222,7 +221,7 @@ def _round_ok(prog, g, prev, state):
     gfn = prog.guard if prog.guard is not None \
         else (lambda g_, p_, s_: finite_state(s_))
     local = jnp.asarray(gfn(g, prev, state), bool)
-    ok = psum_scalar(local.astype(jnp.int32)) == axis_size(AXIS)
+    ok = psum_scalar(local.astype(jnp.int32)) == jax.lax.axis_size(AXIS)
     viol = faults.stamp_violation()
     if viol is not None:
         ok = ok & jnp.logical_not(viol)
@@ -468,6 +467,8 @@ def run_program_batched(prog, g: dict, *batched_inputs,
     Vertex outputs gain a leading (B,) axis; ``rounds`` becomes (B,).
     Works for :class:`PhasedProgram` too (batched betweenness: B forward
     sweeps then B backward sweeps, vmapped as one phased traversal).
+    The ELL gathers keep the lane axis leading under vmap (see
+    ``core/localops.py``), so a lane costs its own temporaries only.
     """
     if not isinstance(prog, PhasedProgram):
         # hoist the loop-invariant prepare out of the vmap so per-query

@@ -36,7 +36,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.compat import axis_size
 from repro.core.partitioned import AXIS, _tap, psum_scalar
 from repro.core.superstep import SuperstepProgram
 
@@ -92,7 +91,7 @@ def triangles_program(n: int, n_local: int) -> SuperstepProgram:
 
     def step(g, state):
         block, tri2, r = state
-        p = axis_size(AXIS)
+        p = jax.lax.axis_size(AXIS)
         # round r holds the block of partition q = (me - r) mod P
         q = (jax.lax.axis_index(AXIS) - r) % p
         a = _unpack_rows(g["adj_bits"], n)          # (n_local, n) my rows

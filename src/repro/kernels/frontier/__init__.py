@@ -1,3 +1,1 @@
-from repro.kernels.frontier.ops import frontier_pull
-
-__all__ = ["frontier_pull"]
+"""BFS pull Pallas kernel (kernel.py) and its pure-jnp oracle (ref.py)."""

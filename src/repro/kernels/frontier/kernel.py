@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.core.compat import tpu_compiler_params
 
 INT_INF = 2 ** 30
 
@@ -55,7 +54,7 @@ def bfs_pull(nbr, bits, unvisited, *, row_block: int = 256,
         ],
         out_specs=pl.BlockSpec((row_block,), lambda r: (r,)),
         out_shape=jax.ShapeDtypeStruct((n_rows,), jnp.int32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(nbr, bits, unvisited.astype(jnp.int32))

@@ -1,3 +1,1 @@
-from repro.kernels.spmv.ops import spmv
-
-__all__ = ["spmv"]
+"""ELL SpMV Pallas kernel (kernel.py) and its pure-jnp oracle (ref.py)."""

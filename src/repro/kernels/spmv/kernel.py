@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.core.compat import tpu_compiler_params
-
 
 def _spmv_kernel(idx_ref, val_ref, x_ref, y_ref):
     idx = idx_ref[...]                        # (RB, K) int32, sentinel = n_pad-1
@@ -52,7 +50,7 @@ def spmv_ell(idx, val, x, *, row_block: int = 256, interpret: bool = False):
         ],
         out_specs=pl.BlockSpec((row_block,), lambda r: (r,)),
         out_shape=jax.ShapeDtypeStruct((n_rows,), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(idx, val, x.astype(jnp.float32))

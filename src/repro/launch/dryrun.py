@@ -45,8 +45,7 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, out_dir, *,
     mem = compiled.memory_analysis()
     print(f"[{arch} x {shape_name} x {mesh_name}] {meta['program']}")
     print(f"  memory_analysis: {mem}")
-    from repro.core.compat import cost_analysis
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     print(f"  cost_analysis: flops={ca.get('flops', 0):.3e} "
           f"bytes={ca.get('bytes accessed', 0):.3e}")
 

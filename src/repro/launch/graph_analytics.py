@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from repro.configs import graph_workloads
 from repro.core import GraphEngine, incremental, partition_graph, registry
 from repro.core.registry import program_label
+from repro.core.runtime import enable_compile_cache
 from repro.graphs import generate_edges
 from repro.launch.mesh import make_graph_mesh
 from repro.obs import chrome_trace, write_trace
@@ -183,6 +184,7 @@ def run(graph_name: str, parts: int, *, pr_iters: int = 50,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--graph", default="urand16")
     ap.add_argument("--parts", type=int, default=len(jax.devices()))

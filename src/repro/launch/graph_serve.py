@@ -45,7 +45,7 @@ import jax
 
 from repro.configs import graph_workloads
 from repro.core import GraphEngine, localops, partition_graph
-from repro.core.compat import runtime_fingerprint
+from repro.core.runtime import enable_compile_cache, runtime_fingerprint
 from repro.graphs import generate_edges
 from repro.launch.mesh import make_graph_mesh
 from repro.obs import SpanRecorder, chrome_trace, trace_summary, \
@@ -181,6 +181,7 @@ def run(graph_name: str, parts: int, *, mix: str = "bfs:8,sssp:4,cc:1",
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         description="Graph query server: coalesced mixed-algorithm "
                     "traffic against a device-resident graph.",

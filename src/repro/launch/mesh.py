@@ -13,7 +13,12 @@ from __future__ import annotations
 
 import jax
 
-from repro.core.compat import make_mesh
+
+def _mesh(shape, axes, devices=None) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with Auto axes (the engine's shard_maps carry
+    their own specs; jax.make_mesh defaults to Explicit axes)."""
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=auto, devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -27,17 +32,17 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
         raise RuntimeError(
             f"production mesh needs {need} devices, found {len(devices)}; "
             "the dry-run sets XLA_FLAGS=--xla_force_host_platform_device_count=512")
-    return make_mesh(shape, axes, devices=devices)
+    return _mesh(shape, axes, devices=devices)
 
 
 def make_local_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
     """Small mesh over whatever devices exist (tests / examples)."""
-    return make_mesh((data, model), ("data", "model"))
+    return _mesh((data, model), ("data", "model"))
 
 
 def make_graph_mesh(parts: int) -> jax.sharding.Mesh:
     """1D mesh for the graph engine: vertex partitions over all chips."""
-    return make_mesh((parts,), ("parts",))
+    return _mesh((parts,), ("parts",))
 
 
 def batch_axes(mesh: jax.sharding.Mesh, batch: int):

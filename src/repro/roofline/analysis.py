@@ -314,8 +314,7 @@ def model_flops(cfg, shape) -> float:
 
 def analyze(compiled, *, arch: str, shape_name: str, mesh_name: str,
             devices: int, model_flops_total: float) -> Roofline:
-    from repro.core.compat import cost_analysis
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     flops = float(ca.get("flops", 0.0))
     nbytes = float(ca.get("bytes accessed", 0.0))
     stats = parse_collectives(compiled.as_text())

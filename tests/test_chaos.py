@@ -140,7 +140,7 @@ def test_value_guard_catches_nan_without_fault_harness():
     """The second detection channel is independent of the fault taps: a
     program whose OWN step writes NaN into float state trips the default
     finite-state screen with no schedule armed at all."""
-    from repro.core.compat import shard_map
+    from jax import shard_map
     from repro.core.superstep import SuperstepProgram, run_program
 
     P = jax.sharding.PartitionSpec

@@ -7,7 +7,9 @@ interpret mode), plus layout/property guards:
     random graphs when hypothesis is installed);
   * whole programs produce identical results under ``layout="ell"`` and
     ``layout="coo"`` (the escape-hatch path compiles the same math);
-  * REPRO_LOCALOPS mode resolution and the set_mode override.
+  * REPRO_LOCALOPS mode resolution and the set_mode override;
+  * the batched gather keeps every lane's answer, and the host build's
+    fast stable sort builds the same graph as ``np.argsort``.
 
 The primitives are pure per-partition compute (no collectives), so they
 are exercised here directly on per-partition graph dicts - the
@@ -18,10 +20,12 @@ gate, which runs the ELL path by default.
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 import oracle
 from repro.core import GraphEngine, localops, partition_graph
+from repro.core import graph as graph_mod
 from repro.core.graph import ELL_BLOCK, ELL_LANE, ell_entries
 from repro.launch.mesh import make_graph_mesh
 
@@ -274,5 +278,52 @@ def test_mode_resolution(monkeypatch):
     monkeypatch.delenv("REPRO_LOCALOPS")
     assert localops.resolve(mode="ref") == "ref"
     assert localops.resolve(mode="kernel") == "pallas"
-    assert localops.resolve(mode="auto", backend="tpu") == "pallas"
+    assert localops.resolve(mode="auto", backend="tpu") == "ell"
     assert localops.resolve(mode="auto", backend="cpu") == "ell"
+
+
+@pytest.mark.parametrize("batched", ["x", "idx", "both", "chunked"])
+def test_take_batching_matches_per_lane(monkeypatch, rng, batched):
+    """vmap of ``_take`` (lane axis leading) equals the per-lane gather,
+    including when the flat offsets split the lanes into chunks."""
+    lanes, n = 5, 300
+    x = jnp.asarray(rng.random((lanes, n)), jnp.float32)
+    idx = jnp.asarray(rng.integers(0, n, (lanes, 7, 40)), jnp.int32)
+    if batched == "chunked":
+        monkeypatch.setattr(localops, "_FLAT_LIMIT", 2 * n)
+    x_ax = None if batched == "idx" else 0
+    i_ax = 0 if batched in ("idx", "both", "chunked") else None
+    xs = x if x_ax == 0 else x[0]
+    ids = idx if i_ax == 0 else idx[0]
+    got = jax.jit(jax.vmap(localops._take, in_axes=(x_ax, i_ax)))(xs, ids)
+    want = np.stack([np.asarray(xs if x_ax is None else xs[b])[
+        np.asarray(ids if i_ax is None else ids[b])] for b in range(lanes)])
+    assert got.shape == (lanes, 7, 40)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def _host_arrays(g):
+    arrs = {k: v for k, v in vars(g).items() if isinstance(v, np.ndarray)}
+    arrs.update(g.ell_arrays)
+    return arrs
+
+
+@pytest.mark.parametrize("family", ["urand", "rmat"])
+@pytest.mark.parametrize("parts", [1, 2, 4])
+def test_partition_matches_plain_argsort(monkeypatch, family, parts):
+    """``_stable_argsort`` is ``np.argsort(kind="stable")`` sped up: a
+    graph built with either is the same, array for array."""
+    edges, n = oracle.family_edges(family, 1024, 9)
+    keys = edges[:, 0] // 7
+    np.testing.assert_array_equal(graph_mod._stable_argsort(keys),
+                                  np.argsort(keys, kind="stable"))
+    fast = partition_graph(edges, n, parts=parts)
+    monkeypatch.setattr(graph_mod, "_stable_argsort",
+                        lambda k: np.argsort(k, kind="stable"))
+    plain = partition_graph(edges, n, parts=parts)
+    assert fast.ell_meta == plain.ell_meta
+    a, b = _host_arrays(fast), _host_arrays(plain)
+    assert a.keys() == b.keys() and len(a) > 6
+    for key in a:
+        assert a[key].dtype == b[key].dtype, key
+        np.testing.assert_array_equal(a[key], b[key], err_msg=key)
