@@ -1,0 +1,8 @@
+"""Device: the share of the window in which no operation ran on the
+device, from the profiler trace, for bfs cells."""
+
+
+def read(run):
+    if run.algo != "bfs" or run.trace is None:
+        return None
+    return (1 - run.trace.busy_s / run.trace.window_s) * 100
