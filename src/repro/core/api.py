@@ -36,7 +36,9 @@ from repro.core import localops, registry
 from repro.core import faults as faults_mod
 from repro.core.graph import GraphShards
 from repro.core.superstep import run_program, run_program_batched
+from repro.obs import scopes as obs_scopes
 from repro.obs import telemetry as obs_telemetry
+from repro.obs.spans import annotate
 
 P = jax.sharding.PartitionSpec
 
@@ -77,13 +79,15 @@ class CompiledProgram:
 
     def __call__(self, garr, *inputs):
         if not self.telemetry:
-            return self.fn(garr, *inputs)
+            with annotate("engine.call"):
+                return self.fn(garr, *inputs)
         # telemetry builds are MEASUREMENT mode: block on the result so
         # the recorded wall-time covers the device work, not just the
         # dispatch (documented perturbation — don't time the dispatch
         # overlap through a telemetry build)
         t0 = time.perf_counter()
-        out = self.fn(garr, *inputs)
+        with annotate("engine.call"):
+            out = self.fn(garr, *inputs)
         jax.block_until_ready(out)
         self.last_wall_s = time.perf_counter() - t0
         return out
@@ -101,8 +105,13 @@ class CompiledProgram:
             series=ps, wire=self.wire.snapshot(), wall_s=self.last_wall_s)
 
     def lower(self, *args):
-        """AOT-lower; defaults to the engine's abstract arg shapes."""
-        return self.fn.lower(*(args if args else self.abstract_args))
+        """AOT-lower; defaults to the engine's abstract arg shapes.  The
+        executable its ``compile()`` returns is kept by ``repro.obs``
+        (``obs.scopes.compiled_scopes``), so a profiler trace of it can
+        be read by device scope."""
+        with annotate("engine.lower"):
+            return _Lowered(self.fn.lower(*(args if args else
+                                            self.abstract_args)))
 
     def aot(self):
         """Lowered + compiled executable against abstract args (cached)."""
@@ -117,6 +126,23 @@ class CompiledProgram:
     def __repr__(self):
         return (f"CompiledProgram({self.program.key}, "
                 f"inputs={self.spec.inputs})")
+
+
+class _Lowered:
+    """A ``jax.stages.Lowered`` whose ``compile()`` hands the executable
+    to :func:`repro.obs.scopes.keep`; every other attribute is the
+    wrapped object's."""
+
+    def __init__(self, lowered):
+        self._lowered = lowered
+
+    def compile(self, *args, **kwargs):
+        compiled = self._lowered.compile(*args, **kwargs)
+        obs_scopes.keep(compiled)
+        return compiled
+
+    def __getattr__(self, name):
+        return getattr(self._lowered, name)
 
 
 @dataclass
@@ -332,9 +358,10 @@ class GraphEngine:
 
     # -- helpers -------------------------------------------------------------
     def device_graph(self):
-        arrs = self.g.device_arrays(self.layout)
-        sh = jax.sharding.NamedSharding(self.mesh, P("parts", None))
-        return {k: jax.device_put(v, sh) for k, v in arrs.items()}
+        with annotate("engine.upload"):
+            arrs = self.g.device_arrays(self.layout)
+            sh = jax.sharding.NamedSharding(self.mesh, P("parts", None))
+            return {k: jax.device_put(v, sh) for k, v in arrs.items()}
 
     def gather_vertex_field(self, arr) -> np.ndarray:
         """(P, n_local) sharded -> (n_orig,) numpy."""

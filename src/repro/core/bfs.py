@@ -41,11 +41,13 @@ from repro.core.monotone import monotone_async_program
 from repro.core.partitioned import AXIS, broadcast_global, \
     exchange_min_int, exchange_or, pack_bits, psum_scalar
 from repro.core.superstep import AsyncSuperstepProgram, SuperstepProgram
+from repro.obs.scopes import device_scope
 
 
 INT_INF = jnp.int32(2 ** 30)
 
 
+@device_scope("bfs.derive_parents")
 def _derive_parents(g, ell_in, gf_packed, unvisited):
     """Owner-side parent derivation by pulling over local in-edges.
 
@@ -196,11 +198,13 @@ def bfs_fast_program(shards, max_levels: int = 64,
     def step(g, state):
         parents, frontier, gf, count = state
 
+        @device_scope("bfs.push")
         def push(_):
             p, f, g2, c = _fast_level_push(g, ell_in, ell_dst, n,
                                            parents, frontier, gf)
             return p, f, g2, c
 
+        @device_scope("bfs.pull")
         def pull(_):
             p, g2, c = _fast_level(g, ell_in, parents, gf)
             # recover local frontier from my slice of the packed bitmap

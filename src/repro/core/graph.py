@@ -60,6 +60,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.obs.spans import annotate
+
 ELL_BLOCK = 128   # rows per ELL block (n and n_local are multiples of 128)
 ELL_LANE = 8      # block widths round up to this many slots
 
@@ -407,12 +409,17 @@ def _build_graph_ells(g: "GraphShards") -> None:
          np.where(in_valid, g.in_src_global, -1), pos,
          n, e_max, ("idx", "inv")),
     ]
+
+    def build(name, rows, vals, n_rows, sentinel, sufs):
+        with annotate(f"graph.ell.{name}"):
+            return build_ell(name, rows, vals, n_rows, sentinel,
+                             device_suffixes=sufs)
+
     # the four builds are independent NumPy work that releases the GIL:
     # building them side by side roughly halves a paper-scale build
-    with ThreadPoolExecutor(max_workers=len(specs)) as pool:
-        built = [pool.submit(build_ell, name, rows, vals, n_rows, sentinel,
-                             device_suffixes=sufs)
-                 for name, rows, vals, n_rows, sentinel, sufs in specs]
+    with annotate("graph.ell"), \
+            ThreadPoolExecutor(max_workers=len(specs)) as pool:
+        built = [pool.submit(build, *spec) for spec in specs]
         for fut in built:
             meta, arrays = fut.result()
             g.ell_meta[meta.name] = meta
@@ -427,8 +434,15 @@ def partition_graph(edges: np.ndarray, n_orig: int, parts: int,
     128 keeps TPU lanes aligned).  Padded vertices have no edges.  The
     blocked-ELL view is built alongside the COO shards unless
     ``build_ell_layout=False`` (then every program traces the COO
-    scatter reference path).
+    scatter reference path).  The build marks its phases on the
+    profiler's clock: ``repro.graph.partition``, ``repro.graph.coo``,
+    ``repro.graph.ell`` and one ``repro.graph.ell.<name>`` per build.
     """
+    with annotate("graph.partition"):
+        return _partition_graph(edges, n_orig, parts, build_ell_layout)
+
+
+def _partition_graph(edges, n_orig, parts, build_ell_layout):
     block = parts * 128
     n = ((n_orig + block - 1) // block) * block
     n_local = n // parts
@@ -445,10 +459,11 @@ def partition_graph(edges: np.ndarray, n_orig: int, parts: int,
     # pad to a lane-friendly multiple
     e_max = ((e_max + 127) // 128) * 128
 
-    out_src_local, out_dst_global, _ = _group_edges(
-        src, dst, parts, n_local, e_max, n, key_local=True)
-    in_dst_local, in_src_global, _ = _group_edges(
-        dst, src, parts, n_local, e_max, n, key_local=True)
+    with annotate("graph.coo"):
+        out_src_local, out_dst_global, _ = _group_edges(
+            src, dst, parts, n_local, e_max, n, key_local=True)
+        in_dst_local, in_src_global, _ = _group_edges(
+            dst, src, parts, n_local, e_max, n, key_local=True)
 
     g = GraphShards(
         n=n, n_orig=n_orig, parts=parts, n_local=n_local, e_max=e_max,

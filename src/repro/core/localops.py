@@ -52,6 +52,11 @@ the lane axis leading.  vmap's own rule for a batched operand gathered
 by shared indices would put the lane axis minor, which a TPU pads to
 128 lanes: batch=8 bfs/fast on urand22 then asked for ~60 GB of a
 16 GB v5e.
+
+Each primitive runs inside its device scope ``localops.<primitive>``,
+each bucket's gather inside ``<ell name>.b<i>`` and the final
+inverse-permutation gather inside ``reorder`` (``obs/scopes.py``), so a
+profiler trace of the ell path can be read per primitive and bucket.
 """
 
 from __future__ import annotations
@@ -63,6 +68,7 @@ import jax.numpy as jnp
 
 from repro.core.graph import EllMeta
 from repro.core.partitioned import test_bit
+from repro.obs.scopes import device_scope
 
 INT_INF = jnp.int32(2 ** 30)
 
@@ -165,21 +171,29 @@ def _test_bit(packed, idx):
 
 
 def _buckets(ell: EllMeta, flat):
-    """Yield (row0, rows, width, (rows, width) idx block) per bucket."""
+    """Yield (device scope, row0, rows, width, (rows, width) idx block)
+    per bucket; the scope ``<ell name>.b<i>`` names the bucket's work."""
     off = 0
     r0 = 0
-    for rows, k in ell.buckets:
+    for i, (rows, k) in enumerate(ell.buckets):
         blk = flat[..., off:off + rows * k].reshape(
             flat.shape[:-1] + (rows, k)) if k else None
-        yield r0, rows, k, blk
+        yield device_scope(f"{ell.name}.b{i}"), r0, rows, k, blk
         off += rows * k
         r0 += rows
+
+
+def _reorder(outs, inv):
+    """Bucket rows back to row order: the inverse-permutation gather."""
+    with device_scope("reorder"):
+        return _take(jnp.concatenate(outs), inv)
 
 
 # ---------------------------------------------------------------------------
 # spmv_pull
 # ---------------------------------------------------------------------------
 
+@device_scope("localops.spmv_pull")
 def spmv_pull(g: dict, ell: EllMeta, x, *, mode: str | None = None):
     """y[row] = sum of x[neighbor] over the row's ELL slots, f32.
 
@@ -202,26 +216,28 @@ def spmv_pull(g: dict, ell: EllMeta, x, *, mode: str | None = None):
     xk = jnp.concatenate([x, jnp.zeros((1,), jnp.float32)])  # sentinel slot
     use_pallas = _use_pallas(mode)
     outs = []
-    for _, rows, k, blk in _buckets(ell, idx):
-        if k == 0:
-            outs.append(jnp.zeros((rows,), jnp.float32))
-            continue
-        if use_pallas:
-            from repro.kernels.spmv.kernel import spmv_ell
-            vmask = (blk != ell.sentinel).astype(jnp.float32)
-            outs.append(spmv_ell(blk, vmask, xk,
-                                 row_block=128, interpret=_interpret()))
-        else:
-            cols = blk.T                        # (k, rows), rows minor
-            outs.append(jnp.where(cols != ell.sentinel, _take(xk, cols),
-                                  0.0).sum(axis=0))
-    return _take(jnp.concatenate(outs), inv)
+    for scope, _, rows, k, blk in _buckets(ell, idx):
+        with scope:
+            if k == 0:
+                outs.append(jnp.zeros((rows,), jnp.float32))
+                continue
+            if use_pallas:
+                from repro.kernels.spmv.kernel import spmv_ell
+                vmask = (blk != ell.sentinel).astype(jnp.float32)
+                outs.append(spmv_ell(blk, vmask, xk,
+                                     row_block=128, interpret=_interpret()))
+            else:
+                cols = blk.T                        # (k, rows), rows minor
+                outs.append(jnp.where(cols != ell.sentinel, _take(xk, cols),
+                                      0.0).sum(axis=0))
+    return _reorder(outs, inv)
 
 
 # ---------------------------------------------------------------------------
 # frontier_pull
 # ---------------------------------------------------------------------------
 
+@device_scope("localops.frontier_pull")
 def frontier_pull(g: dict, ell: EllMeta, bits, unvisited, *,
                   mode: str | None = None):
     """Min-id in-neighbor of each row present in the packed frontier.
@@ -251,27 +267,29 @@ def frontier_pull(g: dict, ell: EllMeta, bits, unvisited, *,
     bits_g = jnp.concatenate([bits, jnp.zeros((1,), jnp.uint32)])
     use_pallas = _use_pallas(mode)
     outs = []
-    for r0, rows, k, blk in _buckets(ell, idx):
-        if k == 0:
-            outs.append(jnp.full((rows,), INT_INF, jnp.int32))
-            continue
-        unv_b = unv_ell[r0:r0 + rows]
-        if use_pallas:
-            from repro.kernels.frontier.kernel import bfs_pull
-            outs.append(bfs_pull(blk, bits_g, unv_b.astype(jnp.int32),
-                                 row_block=128, interpret=_interpret()))
-        else:
-            cols = blk.T
-            hit = _test_bit(bits_g, cols) == 1
-            cand = jnp.where(hit, cols, INT_INF).min(axis=0)
-            outs.append(jnp.where(unv_b, cand, INT_INF))
-    return _take(jnp.concatenate(outs), inv)
+    for scope, r0, rows, k, blk in _buckets(ell, idx):
+        with scope:
+            if k == 0:
+                outs.append(jnp.full((rows,), INT_INF, jnp.int32))
+                continue
+            unv_b = unv_ell[r0:r0 + rows]
+            if use_pallas:
+                from repro.kernels.frontier.kernel import bfs_pull
+                outs.append(bfs_pull(blk, bits_g, unv_b.astype(jnp.int32),
+                                     row_block=128, interpret=_interpret()))
+            else:
+                cols = blk.T
+                hit = _test_bit(bits_g, cols) == 1
+                cand = jnp.where(hit, cols, INT_INF).min(axis=0)
+                outs.append(jnp.where(unv_b, cand, INT_INF))
+    return _reorder(outs, inv)
 
 
 # ---------------------------------------------------------------------------
 # pull_min_eq
 # ---------------------------------------------------------------------------
 
+@device_scope("localops.pull_min_eq")
 def pull_min_eq(g: dict, ell: EllMeta, xg, target, *,
                 mode: str | None = None):
     """Min-id in-neighbor ``u`` of each row ``v`` with ``xg[u] ==
@@ -307,14 +325,15 @@ def pull_min_eq(g: dict, ell: EllMeta, xg, target, *,
     # unreached rows)
     xg_g = jnp.concatenate([xg, jnp.full((1,), INT_INF, xg.dtype)])
     outs = []
-    for r0, rows, k, blk in _buckets(ell, idx):
-        if k == 0:
-            outs.append(jnp.full((rows,), INT_INF, jnp.int32))
-            continue
-        cols = blk.T
-        hit = _take(xg_g, cols) == tgt_ell[r0:r0 + rows][None, :]
-        outs.append(jnp.where(hit, cols, INT_INF).min(axis=0))
-    return _take(jnp.concatenate(outs), inv)
+    for scope, r0, rows, k, blk in _buckets(ell, idx):
+        with scope:
+            if k == 0:
+                outs.append(jnp.full((rows,), INT_INF, jnp.int32))
+                continue
+            cols = blk.T
+            hit = _take(xg_g, cols) == tgt_ell[r0:r0 + rows][None, :]
+            outs.append(jnp.where(hit, cols, INT_INF).min(axis=0))
+    return _reorder(outs, inv)
 
 
 # ---------------------------------------------------------------------------
@@ -330,6 +349,7 @@ _REDUCERS = {
 }
 
 
+@device_scope("localops.scatter_combine")
 def scatter_combine(g: dict, ell: EllMeta, vals, op: str, *, identity,
                     mode: str | None = None):
     """Combine per-edge ``vals`` into a (n_rows,) accumulator with ``op``.
@@ -365,15 +385,16 @@ def scatter_combine(g: dict, ell: EllMeta, vals, op: str, *, identity,
     kernel_add = (op == "add" and vals.dtype == jnp.float32
                   and _use_pallas(mode))
     outs = []
-    for _, rows, k, blk in _buckets(ell, idx):
-        if k == 0:
-            outs.append(jnp.full((rows,), identity, vals.dtype))
-            continue
-        if kernel_add:
-            from repro.kernels.spmv.kernel import spmv_ell
-            vmask = (blk != ell.sentinel).astype(jnp.float32)
-            outs.append(spmv_ell(blk, vmask, vpad, row_block=128,
-                                 interpret=_interpret()))
-        else:
-            outs.append(_REDUCERS[op](_take(vpad, blk.T)))
-    return _take(jnp.concatenate(outs), inv)
+    for scope, _, rows, k, blk in _buckets(ell, idx):
+        with scope:
+            if k == 0:
+                outs.append(jnp.full((rows,), identity, vals.dtype))
+                continue
+            if kernel_add:
+                from repro.kernels.spmv.kernel import spmv_ell
+                vmask = (blk != ell.sentinel).astype(jnp.float32)
+                outs.append(spmv_ell(blk, vmask, vpad, row_block=128,
+                                     interpret=_interpret()))
+            else:
+                outs.append(_REDUCERS[op](_take(vpad, blk.T)))
+    return _reorder(outs, inv)

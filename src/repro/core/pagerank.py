@@ -39,6 +39,7 @@ from repro.core import localops
 from repro.core.partitioned import AXIS, broadcast_global, exchange_sum, \
     exchange_sum_finish, exchange_sum_start, psum_scalar
 from repro.core.superstep import AsyncSuperstepProgram, SuperstepProgram
+from repro.obs.scopes import device_scope
 
 
 ALPHA = 0.85
@@ -158,12 +159,14 @@ def pagerank_fast_program(shards, iters: int = 50,
             g, ell_dst, jnp.where(valid, contrib[srcl], 0.0), "add",
             identity=jnp.float32(0.0))
 
+        @device_scope("pagerank.compressed")
         def compressed(_):
             # error-feedback quantization: ship bf16, keep the residual
             payload = (acc + resid).astype(jnp.bfloat16)
             new_resid = (acc + resid) - payload.astype(jnp.float32)
             return exchange_sum(payload).astype(jnp.float32), new_resid
 
+        @device_scope("pagerank.exact")
         def exact(_):
             return exchange_sum(acc + resid), jnp.zeros_like(resid)
 

@@ -36,6 +36,11 @@ report) addresses both execution modes.  ``psum_scalar`` is NOT
 tapped: the BSP halt scalar is control plane, not payload — async
 programs piggyback their halt count on the data exchange, where it IS
 faultable (and counted).
+
+Each primitive runs inside its device scope ``exchange.<op>``
+(``obs/scopes.py``; ``psum_scalar`` is ``exchange.psum``), so a
+profiler trace can tell exchange time from local work.  At parts=1
+every exchange is the identity and its scope holds next to nothing.
 """
 
 from __future__ import annotations
@@ -47,6 +52,7 @@ import jax.numpy as jnp
 
 from repro.core import faults
 from repro.obs import telemetry as obs_telemetry
+from repro.obs.scopes import device_scope
 
 AXIS = "parts"
 
@@ -90,6 +96,7 @@ def local_slice_bounds(n_local: int):
     return lo, lo + n_local
 
 
+@device_scope("exchange.sum")
 def exchange_sum(acc_global, axis_name: str = AXIS):
     """acc_global: (n,) proposed updates for ALL vertices (local view).
 
@@ -102,6 +109,7 @@ def exchange_sum(acc_global, axis_name: str = AXIS):
                                 tiled=False).reshape(-1)
 
 
+@device_scope("exchange.or")
 def exchange_or(mask_global, axis_name: str = AXIS):
     """Boolean OR-combine: frontiers/activation masks.
 
@@ -122,6 +130,7 @@ def exchange_or(mask_global, axis_name: str = AXIS):
     return unpack_bits(acc, mask_global.shape[0] // parts)
 
 
+@device_scope("exchange.min")
 def exchange_min_int(val_global, axis_name: str = AXIS, big=None):
     """Element-wise MIN combine of proposals (any ordered dtype —
     int32 parents/labels, f32 distances).
@@ -137,12 +146,14 @@ def exchange_min_int(val_global, axis_name: str = AXIS, big=None):
     return rows.min(axis=(0, 1))
 
 
+@device_scope("exchange.bcast")
 def broadcast_global(local_vals, axis_name: str = AXIS):
     """(n_local,) -> (n,) full replica (all-gather)."""
     return jax.lax.all_gather(_tap("bcast", local_vals, axis_name),
                               axis_name, axis=0, tiled=True)
 
 
+@device_scope("exchange.psum")
 def psum_scalar(x, axis_name: str = AXIS):
     return jax.lax.psum(x, axis_name)
 
@@ -171,6 +182,7 @@ def psum_scalar(x, axis_name: str = AXIS):
 # --------------------------------------------------------------------------
 
 
+@device_scope("exchange.min_start")
 def exchange_min_start(val_global, scalar, axis_name: str = AXIS):
     """Issue the MIN-combine exchange of ``(n,)`` proposals without
     reducing.  ``scalar`` (the piggybacked halt count) is appended as a
@@ -186,6 +198,7 @@ def exchange_min_start(val_global, scalar, axis_name: str = AXIS):
                               axis_name, split_axis=0, concat_axis=1)
 
 
+@device_scope("exchange.min_finish")
 def exchange_min_finish(handle):
     """Pure-local reduction of an :func:`exchange_min_start` handle:
     ``((n_local,) combined minima, global scalar sum)``."""
@@ -193,6 +206,7 @@ def exchange_min_finish(handle):
     return rows[:, :-1].min(axis=0), rows[:, -1].sum()
 
 
+@device_scope("exchange.sum_start")
 def exchange_sum_start(acc_global, scalar, axis_name: str = AXIS):
     """Issue the SUM-combine reduce-scatter of ``(n,)`` proposals with a
     piggybacked scalar column.  ``psum_scatter`` combines on the wire, so
@@ -209,11 +223,13 @@ def exchange_sum_start(acc_global, scalar, axis_name: str = AXIS):
                                 tiled=False)
 
 
+@device_scope("exchange.sum_finish")
 def exchange_sum_finish(handle):
     """``((n_local,) combined sums, global scalar sum)``."""
     return handle[:-1], handle[-1]
 
 
+@device_scope("exchange.or_start")
 def exchange_or_start(mask_global, scalar, axis_name: str = AXIS):
     """Issue the bit-packed OR exchange of an ``(n,)`` bool mask with a
     piggybacked uint32 count word.  Returns the ``(1, P, n_words + 1)``
@@ -230,6 +246,7 @@ def exchange_or_start(mask_global, scalar, axis_name: str = AXIS):
                               axis_name, split_axis=0, concat_axis=1)
 
 
+@device_scope("exchange.or_finish")
 def exchange_or_finish(handle, n_local: int):
     """``((n_local,) bool OR-combined mask, global int32 scalar sum)``."""
     rows = handle[0]                            # (P, n_words + 1)

@@ -24,6 +24,13 @@ driver (BSP scan vs early-exit, single- vs multi-source) never touches
 algorithm code.  All callables run INSIDE ``shard_map`` over the
 1-D "parts" axis; ``core/api.py`` owns the jit/shard_map wrapping and
 the compile cache.
+
+The drivers name their device work for a profiler trace
+(``obs/scopes.py``): ``superstep.init``, ``superstep.loop`` (the loop
+and what XLA adds to it), inside it ``superstep.step`` (a round, with
+its round count) and ``superstep.halt`` (the loop condition),
+``superstep.guard`` and ``superstep.telemetry`` where compiled in, and
+``superstep.outputs``.
 """
 
 from __future__ import annotations
@@ -38,6 +45,7 @@ import jax.numpy as jnp
 from repro.core import faults
 from repro.core.partitioned import AXIS, psum_scalar
 from repro.obs import telemetry as obs_tel
+from repro.obs.scopes import device_scope
 
 
 @dataclass(frozen=True)
@@ -180,11 +188,13 @@ class AsyncSuperstepProgram:
 # --------------------------------------------------------------------------
 
 
+@device_scope("superstep.telemetry")
 def _series_init(prog):
     return jnp.zeros((prog.max_rounds, 2 + len(prog.probe_names)),
                      jnp.float32)
 
 
+@device_scope("superstep.telemetry")
 def _series_write(prog, series, r, state):
     halted = jnp.asarray(prog.halt(state)).astype(jnp.float32).reshape(())
     probes = tuple(prog.probe(state)) if prog.probe is not None else ()
@@ -216,6 +226,7 @@ def finite_state(state):
     return ok
 
 
+@device_scope("superstep.guard")
 def _round_ok(prog, g, prev, state):
     """Uniform per-round verdict: invariant guard AND transport stamps."""
     gfn = prog.guard if prog.guard is not None \
@@ -245,29 +256,34 @@ def run_program_async(prog: AsyncSuperstepProgram, g: dict, *inputs,
     if telemetry and static_iters:
         raise ValueError("telemetry requires the while-loop driver "
                          "(static_iters=0)")
-    g = prog.prepare(g)
     obs_tel.phase("init")
     faults.set_round(jnp.int32(0))
-    state0, handle0 = prog.init(g, *inputs)
+    with device_scope("superstep.init"):
+        g = prog.prepare(g)
+        state0, handle0 = prog.init(g, *inputs)
 
     if static_iters:
         def sbody(carry, _):
             state, handle, r = carry
             faults.set_round(r + 1)
-            state, handle = prog.fold(g, prog.local(g, state), handle)
-            return (state, handle, r + 1), None
+            with device_scope("superstep.step"):
+                state, handle = prog.fold(g, prog.local(g, state), handle)
+                return (state, handle, r + 1), None
 
         obs_tel.phase("round")
-        (state, _, rounds), _ = jax.lax.scan(
-            sbody, (state0, handle0, jnp.int32(0)), None,
-            length=static_iters)
+        with device_scope("superstep.loop"):
+            (state, _, rounds), _ = jax.lax.scan(
+                sbody, (state0, handle0, jnp.int32(0)), None,
+                length=static_iters)
         faults.set_round(jnp.int32(-1))   # outputs are not addressable
         obs_tel.phase("outputs")
-        return prog.outputs(g, state), rounds
+        with device_scope("superstep.outputs"):
+            return prog.outputs(g, state), rounds
 
     ok0 = _round_ok(prog, g, state0, state0) if guard else ()
     series0 = _series_init(prog) if telemetry else ()
 
+    @device_scope("superstep.halt")
     def cond(carry):
         state, _, r, ok, _series = carry
         live = jnp.logical_not(prog.halt(state)) & (r < prog.max_rounds)
@@ -277,19 +293,23 @@ def run_program_async(prog: AsyncSuperstepProgram, g: dict, *inputs,
         state, handle, r, ok, series = carry
         faults.set_round(r + 1)
         prev = state
-        state, handle = prog.fold(g, prog.local(g, state), handle)
+        with device_scope("superstep.step"):
+            state, handle = prog.fold(g, prog.local(g, state), handle)
+            r_next = r + 1
         if guard:
             ok = ok & _round_ok(prog, g, prev, state)
         if telemetry:
             series = _series_write(prog, series, r, state)
-        return state, handle, r + 1, ok, series
+        return state, handle, r_next, ok, series
 
     obs_tel.phase("round")
-    state, _, rounds, ok, series = jax.lax.while_loop(
-        cond, body, (state0, handle0, jnp.int32(0), ok0, series0))
+    with device_scope("superstep.loop"):
+        state, _, rounds, ok, series = jax.lax.while_loop(
+            cond, body, (state0, handle0, jnp.int32(0), ok0, series0))
     faults.set_round(jnp.int32(-1))
     obs_tel.phase("outputs")
-    res = (prog.outputs(g, state), rounds)
+    with device_scope("superstep.outputs"):
+        res = (prog.outputs(g, state), rounds)
     if guard:
         res += (ok,)
     if telemetry:
@@ -409,27 +429,32 @@ def run_program(prog, g: dict, *inputs, static_iters: int = 0,
         return run_program_async(prog, g, *inputs,
                                  static_iters=static_iters, guard=guard,
                                  telemetry=telemetry)
-    g = prog.prepare(g)
     obs_tel.phase("init")
     faults.set_round(jnp.int32(0))
-    state0 = prog.init(g, *inputs)
+    with device_scope("superstep.init"):
+        g = prog.prepare(g)
+        state0 = prog.init(g, *inputs)
 
     if static_iters:
         def sbody(carry, _):
             state, r = carry
             faults.set_round(r)
-            return (prog.step(g, state), r + 1), None
+            with device_scope("superstep.step"):
+                return (prog.step(g, state), r + 1), None
 
         obs_tel.phase("round")
-        (state, rounds), _ = jax.lax.scan(
-            sbody, (state0, jnp.int32(0)), None, length=static_iters)
+        with device_scope("superstep.loop"):
+            (state, rounds), _ = jax.lax.scan(
+                sbody, (state0, jnp.int32(0)), None, length=static_iters)
         faults.set_round(jnp.int32(-1))   # outputs are not addressable
         obs_tel.phase("outputs")
-        return prog.outputs(state), rounds
+        with device_scope("superstep.outputs"):
+            return prog.outputs(state), rounds
 
     ok0 = _round_ok(prog, g, state0, state0) if guard else ()
     series0 = _series_init(prog) if telemetry else ()
 
+    @device_scope("superstep.halt")
     def cond(carry):
         state, r, ok, _series = carry
         live = jnp.logical_not(prog.halt(state)) & (r < prog.max_rounds)
@@ -438,19 +463,23 @@ def run_program(prog, g: dict, *inputs, static_iters: int = 0,
     def body(carry):
         state, r, ok, series = carry
         faults.set_round(r)
-        new = prog.step(g, state)
+        with device_scope("superstep.step"):
+            new = prog.step(g, state)
+            r_next = r + 1
         if guard:
             ok = ok & _round_ok(prog, g, state, new)
         if telemetry:
             series = _series_write(prog, series, r, new)
-        return new, r + 1, ok, series
+        return new, r_next, ok, series
 
     obs_tel.phase("round")
-    state, rounds, ok, series = jax.lax.while_loop(
-        cond, body, (state0, jnp.int32(0), ok0, series0))
+    with device_scope("superstep.loop"):
+        state, rounds, ok, series = jax.lax.while_loop(
+            cond, body, (state0, jnp.int32(0), ok0, series0))
     faults.set_round(jnp.int32(-1))
     obs_tel.phase("outputs")
-    res = (prog.outputs(state), rounds)
+    with device_scope("superstep.outputs"):
+        res = (prog.outputs(state), rounds)
     if guard:
         res += (ok,)
     if telemetry:
@@ -473,7 +502,8 @@ def run_program_batched(prog, g: dict, *batched_inputs,
     if not isinstance(prog, PhasedProgram):
         # hoist the loop-invariant prepare out of the vmap so per-query
         # traversals share one derived-edge-data computation
-        g = prog.prepare(g)
+        with device_scope("superstep.init"):
+            g = prog.prepare(g)
         prog = dataclasses.replace(prog, prepare=lambda garr: garr)
 
     def one(*ins):
@@ -507,14 +537,15 @@ def init_carry(prog, g: dict, *inputs, telemetry: bool = False):
     poison).  ``telemetry=True`` appends the series buffer as carry[4]
     — it checkpoints, rolls back, and restores like any state leaf, so
     a recovered run's series has no rows from discarded chunks."""
-    g = prog.prepare(g)
     obs_tel.phase("init")
     faults.set_round(jnp.int32(0))
-    if isinstance(prog, AsyncSuperstepProgram):
-        state0, handle0 = prog.init(g, *inputs)
-    else:
-        state0 = prog.init(g, *inputs)
-        handle0 = ()
+    with device_scope("superstep.init"):
+        g = prog.prepare(g)
+        if isinstance(prog, AsyncSuperstepProgram):
+            state0, handle0 = prog.init(g, *inputs)
+        else:
+            state0 = prog.init(g, *inputs)
+            handle0 = ()
     ok0 = _round_ok(prog, g, state0, state0)
     base = (state0, handle0, jnp.int32(0), ok0)
     return base + (_series_init(prog),) if telemetry else base
@@ -530,10 +561,12 @@ def run_chunk(prog, g: dict, carry, chunk: int):
     5-element carry (from ``init_carry(telemetry=True)``) carries the
     telemetry series and writes its row each round.
     """
-    g = prog.prepare(g)
+    with device_scope("superstep.init"):
+        g = prog.prepare(g)
     is_async = isinstance(prog, AsyncSuperstepProgram)
     telemetry = len(carry) == 5
 
+    @device_scope("superstep.halt")
     def cond(c):
         (state, _, r, ok, *_), i = c
         return ok & jnp.logical_not(prog.halt(state)) \
@@ -543,27 +576,31 @@ def run_chunk(prog, g: dict, carry, chunk: int):
         (state, handle, r, ok, *rest), i = c
         faults.set_round(r + 1 if is_async else r)
         prev = state
-        if is_async:
-            state, handle = prog.fold(g, prog.local(g, state), handle)
-        else:
-            state = prog.step(g, state)
+        with device_scope("superstep.step"):
+            if is_async:
+                state, handle = prog.fold(g, prog.local(g, state), handle)
+            else:
+                state = prog.step(g, state)
+            r_next, i_next = r + 1, i + 1
         ok = ok & _round_ok(prog, g, prev, state)
-        new = (state, handle, r + 1, ok)
+        new = (state, handle, r_next, ok)
         if telemetry:
             new += (_series_write(prog, rest[0], r, state),)
-        return new, i + 1
+        return new, i_next
 
     obs_tel.phase("round")
-    carry, _ = jax.lax.while_loop(cond, body, (carry, jnp.int32(0)))
+    with device_scope("superstep.loop"):
+        carry, _ = jax.lax.while_loop(cond, body, (carry, jnp.int32(0)))
     faults.set_round(jnp.int32(-1))
     return carry, jnp.asarray(prog.halt(carry[0]), bool)
 
 
 def carry_outputs(prog, g: dict, carry):
     """Finalize a halted carry into the program's outputs tuple."""
-    g = prog.prepare(g)
     faults.set_round(jnp.int32(-1))
     state = carry[0]
-    if isinstance(prog, AsyncSuperstepProgram):
-        return prog.outputs(g, state)
-    return prog.outputs(state)
+    with device_scope("superstep.outputs"):
+        g = prog.prepare(g)
+        if isinstance(prog, AsyncSuperstepProgram):
+            return prog.outputs(g, state)
+        return prog.outputs(state)

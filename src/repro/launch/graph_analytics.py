@@ -13,7 +13,10 @@ to the COO scatter reference path (the default ``ell`` routes every
 hot loop through the blocked-ELL local ops in ``core/localops.py``).
 ``--obs`` re-runs each program with engine telemetry on (per-round
 halt/probe series + wire bytes per exchange primitive, ``repro.obs``)
-and ``--trace-out trace.json`` exports those runs as a Chrome trace.
+and ``--profile-dir DIR`` runs the programs under a ``jax.profiler``
+trace written to DIR: it names the device work by scope
+(``superstep.step``, ``localops.spmv_pull``, ...; ``obs/scopes.py``)
+and the host phases as ``repro.*`` spans.
 
   PYTHONPATH=src python -m repro.launch.graph_analytics --graph urand18
   XLA_FLAGS=--xla_force_host_platform_device_count=8 \
@@ -37,7 +40,6 @@ from repro.core.registry import program_label
 from repro.core.runtime import enable_compile_cache
 from repro.graphs import generate_edges
 from repro.launch.mesh import make_graph_mesh
-from repro.obs import chrome_trace, write_trace
 
 def _timed(fn, args):
     out = fn(*args)               # compile
@@ -51,7 +53,7 @@ def _timed(fn, args):
 def run(graph_name: str, parts: int, *, pr_iters: int = 50,
         verify: bool = True, seed: int = 42, multi_source: int = 0,
         layout: str = "ell", exec_mode: str = "all", obs: bool = False,
-        trace_out: str | None = None):
+        profile_dir: str | None = None):
     from repro.core import localops
     gcfg = graph_workloads.ALL[graph_name]
     print(f"[graph] generating {graph_name}: 2^{gcfg.scale} vertices, "
@@ -67,8 +69,8 @@ def run(graph_name: str, parts: int, *, pr_iters: int = 50,
     garr = eng.device_graph()
     root = jnp.int32(0)
     results = {}
-    obs = obs or bool(trace_out)
-    engine_tracks = []     # (label, RunTelemetry, parts) for the export
+    if profile_dir:
+        jax.profiler.start_trace(profile_dir)
 
     for algo, variant in registry.available():
         spec = registry.get_spec(algo, variant)
@@ -98,9 +100,7 @@ def run(graph_name: str, parts: int, *, pr_iters: int = 50,
             # stays the un-instrumented number
             tprog = eng.program(algo, variant, telemetry=True, **params)
             tout = tprog(*args)
-            tel = tprog.run_telemetry(tout[-1])
-            engine_tracks.append((name, tel, parts))
-            s = tel.summary()
+            s = tprog.run_telemetry(tout[-1]).summary()
             wire = s.get("wire_bytes_per_round", {})
             print(f"[obs]   {name:14s} rounds={s['rounds']:3d} "
                   f"wall={s.get('wall_ms', 0.0):8.1f} ms  wire/round="
@@ -124,6 +124,11 @@ def run(graph_name: str, parts: int, *, pr_iters: int = 50,
             results[name] = (out, dt)
             print(f"[graph] {name:14s} {dt*1e3:9.1f} ms "
                   f"({dt*1e3/multi_source:7.1f} ms/query)")
+
+    if profile_dir:
+        jax.profiler.stop_trace()
+        print(f"[graph] wrote a profiler trace under {profile_dir} "
+              "(open in TensorBoard or ui.perfetto.dev)")
 
     if verify:
         if "bfs_bsp" in results and "bfs_fast" in results:
@@ -175,11 +180,6 @@ def run(graph_name: str, parts: int, *, pr_iters: int = 50,
             same = ((mb[0] < 2 ** 30) == (p_fast < 2 ** 30)).all()
             print(f"[verify] multi-source BFS root0 == single-source: "
                   f"{bool(same)}")
-
-    if trace_out and engine_tracks:
-        counts = write_trace(trace_out, chrome_trace(engine=engine_tracks))
-        print(f"[graph] wrote {trace_out} (chrome trace, "
-              f"{sum(counts.values())} events; open in ui.perfetto.dev)")
     return results
 
 
@@ -208,16 +208,16 @@ def main():
                     help="also run each program with telemetry=True "
                          "(separate compile-cache entry) and report "
                          "per-round series + wire bytes per primitive")
-    ap.add_argument("--trace-out", default=None,
-                    help="write a Chrome trace-event JSON of the "
-                         "telemetry runs (implies --obs; open in "
-                         "ui.perfetto.dev)")
+    ap.add_argument("--profile-dir", default=None,
+                    help="run the programs under a jax.profiler trace "
+                         "written to this directory (device work named "
+                         "by scope, host phases as repro.* spans)")
     ap.add_argument("--no-verify", action="store_true")
     args = ap.parse_args()
     run(args.graph, args.parts, pr_iters=args.pr_iters,
         verify=not args.no_verify, multi_source=args.multi_source,
         layout=args.layout, exec_mode=args.exec_mode, obs=args.obs,
-        trace_out=args.trace_out)
+        profile_dir=args.profile_dir)
 
 
 if __name__ == "__main__":

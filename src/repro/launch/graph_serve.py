@@ -25,6 +25,10 @@ bounded ring, see ``repro.obs``) and prints a trace summary;
 ``--trace-out trace.json`` additionally exports the session as Chrome
 trace-event JSON for ui.perfetto.dev (implies ``--obs``).  The
 ``--json`` payload gains a ``trace_summary`` block when tracing is on.
+``--profile-dir DIR`` runs the session under a ``jax.profiler`` trace
+written to DIR (implies ``--obs``): the serve spans land there as
+``repro.<component>.<kind>`` beside the host build's ``repro.graph.*``
+spans and the device work, named by scope.
 
 (Use XLA_FLAGS=--xla_force_host_platform_device_count=N for --parts N
 on a single host, as with repro.launch.graph_analytics.)
@@ -61,12 +65,14 @@ def run(graph_name: str, parts: int, *, mix: str = "bfs:8,sssp:4,cc:1",
         mutate_every: float = 0.0, mutate_size: int = 64,
         wal_dir: str | None = None, snapshot_every: int = 8,
         recover: bool = False, obs: bool = False,
-        trace_out: str | None = None):
+        trace_out: str | None = None, profile_dir: str | None = None):
     gcfg = graph_workloads.ALL[graph_name]
     # --trace-out implies tracing; a SpanRecorder on the server records
     # every pipeline stage (admission -> ... -> demux) plus durability
     # spans and resilience events
-    rec = SpanRecorder() if (obs or trace_out) else None
+    rec = SpanRecorder() if (obs or trace_out or profile_dir) else None
+    if profile_dir:
+        jax.profiler.start_trace(profile_dir)
     edges = None
     if recover:
         if not wal_dir:
@@ -124,6 +130,9 @@ def run(graph_name: str, parts: int, *, mix: str = "bfs:8,sssp:4,cc:1",
           f"{duration:.0f}s (rate={rate:.0f}/s, mix={mix}, "
           f"zipf_s={zipf_s})")
     results = server.serve_trace(trace)
+    if profile_dir:
+        jax.profiler.stop_trace()
+        print(f"[serve] wrote a profiler trace under {profile_dir}")
     print(f"[serve] served {len(results)} queries "
           f"({len(results)/server.metrics.window_s:.1f} q/s overall)")
     if server.mutation_log:
@@ -226,6 +235,9 @@ def main():
     ap.add_argument("--trace-out", default=None,
                     help="write a Chrome trace-event JSON of the serve "
                          "session (implies --obs; open in ui.perfetto.dev)")
+    ap.add_argument("--profile-dir", default=None,
+                    help="run the session under a jax.profiler trace "
+                         "written to this directory (implies --obs)")
     args = ap.parse_args()
     run(args.graph, args.parts, mix=args.mix, duration=args.duration,
         rate=args.rate,
@@ -234,7 +246,8 @@ def main():
         layout=args.layout, json_path=args.json,
         mutate_every=args.mutate_every, mutate_size=args.mutate_size,
         wal_dir=args.wal_dir, snapshot_every=args.snapshot_every,
-        recover=args.recover, obs=args.obs, trace_out=args.trace_out)
+        recover=args.recover, obs=args.obs, trace_out=args.trace_out,
+        profile_dir=args.profile_dir)
 
 
 if __name__ == "__main__":

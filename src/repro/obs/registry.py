@@ -15,14 +15,28 @@ Everything the subsystem can emit is declared HERE, statically:
     report knows about.  ``Registry`` refuses undeclared names, so an
     instrumentation site cannot silently invent a metric the docs
     table doesn't list.
+  * ``DEVICE_SCOPES`` — the ``jax.named_scope`` names the engine wraps
+    its device work in (``obs/scopes.py``); they reach the compiled
+    HLO as ``op_name`` metadata, where ``op_scopes`` reads them back.
+  * ``PROGRAM_SPANS`` — the host phases the engine annotates on the
+    profiler's clock as ``repro.<name>`` (``obs/spans.annotate``).
+    Serve spans reach the profiler too, as
+    ``repro.<component>.<kind>``.
+
+A ``<placeholder>`` in a scope or span name stands for one identifier
+(``<ell>.b<i>`` covers ``ell_in.b3``); ``declared()`` matches names
+against them and the annotation helpers refuse any other name.
 
 ``docs/API.md``'s Observability tables are generated by
-``spans_markdown_table()`` / ``instruments_markdown_table()`` and
-drift-tested with the same ``table in docs`` idiom as the algorithm
-and crash-point tables.
+``spans_markdown_table()`` / ``instruments_markdown_table()`` /
+``scopes_markdown_table()`` and drift-tested with the same ``table in
+docs`` idiom as the algorithm and crash-point tables.
 """
 
 from __future__ import annotations
+
+import functools
+import re
 
 import numpy as np
 
@@ -33,7 +47,6 @@ COMPONENTS = {
     "executor": "double-buffered executor host side",
     "device": "device launches (dispatch -> done)",
     "durability": "WAL appends and snapshot writes",
-    "engine": "superstep rounds from engine telemetry",
     "recovery": "checkpoint runner (chunks, detections, rollbacks)",
 }
 
@@ -62,9 +75,6 @@ SPAN_KINDS = {
                  "crash-consistent snapshot write"),
     "recovery": ("server", "complete",
                  "GraphServer.recover(): snapshot load + WAL replay"),
-    "engine_round": ("engine", "complete",
-                     "one superstep (engine telemetry; rounds splay "
-                     "the measured wall-time uniformly)"),
     "chunk": ("recovery", "complete",
               "one checkpointed chunk of rounds in CheckpointRunner"),
 }
@@ -99,6 +109,103 @@ INSTRUMENTS = {
     "device_ms": ("histogram", "ms", "device launch wall-time"),
     "wal_append_ms": ("histogram", "ms", "WAL append wall-time"),
 }
+
+# device scope -> (module, description); nested scopes join with "/"
+DEVICE_SCOPES = {
+    "superstep.init": ("core/superstep.py",
+                       "prepare + init: the state before round one"),
+    "superstep.loop": ("core/superstep.py",
+                       "the round loop: its control and the carry "
+                       "copies XLA adds"),
+    "superstep.step": ("core/superstep.py",
+                       "one round of the program, and the round count"),
+    "superstep.halt": ("core/superstep.py",
+                       "the loop condition: halt test and round cap"),
+    "superstep.guard": ("core/superstep.py",
+                        "per-round invariant check (guard=True builds)"),
+    "superstep.telemetry": ("core/superstep.py",
+                            "per-round series row (telemetry=True "
+                            "builds)"),
+    "superstep.outputs": ("core/superstep.py",
+                          "final outputs from the halted state"),
+    "bfs.push": ("core/bfs.py",
+                 "bfs/fast push level: scatter-OR over out-edges"),
+    "bfs.pull": ("core/bfs.py",
+                 "bfs/fast pull level: pull over in-edges"),
+    "bfs.derive_parents": ("core/bfs.py",
+                           "min-id in-frontier parent of each new "
+                           "vertex"),
+    "pagerank.compressed": ("core/pagerank.py",
+                            "pagerank/fast step shipping bf16 with "
+                            "error feedback"),
+    "pagerank.exact": ("core/pagerank.py",
+                       "pagerank/fast step shipping f32"),
+    "localops.spmv_pull": ("core/localops.py",
+                           "sum of x over in-neighbours (SpMV)"),
+    "localops.frontier_pull": ("core/localops.py",
+                               "min-id in-neighbour in the frontier"),
+    "localops.pull_min_eq": ("core/localops.py",
+                             "min-id in-neighbour with a matching "
+                             "value"),
+    "localops.scatter_combine": ("core/localops.py",
+                                 "combine per-edge values per row"),
+    "<ell>.b<i>": ("core/localops.py",
+                   "gather and row reduction of bucket i of an ELL "
+                   "structure"),
+    "reorder": ("core/localops.py",
+                "bucket rows back to row order (inverse permutation)"),
+    "exchange.sum": ("core/partitioned.py", "reduce-scatter SUM"),
+    "exchange.or": ("core/partitioned.py",
+                    "bit-packed all-to-all OR"),
+    "exchange.min": ("core/partitioned.py", "all-to-all MIN"),
+    "exchange.bcast": ("core/partitioned.py",
+                       "all-gather of a vertex field"),
+    "exchange.psum": ("core/partitioned.py",
+                      "all-reduce of a scalar (halt counts)"),
+    "exchange.<op>_start": ("core/partitioned.py",
+                            "issue a double-buffered exchange"),
+    "exchange.<op>_finish": ("core/partitioned.py",
+                             "reduce a double-buffered exchange's "
+                             "handle"),
+}
+
+# host span (profiler name repro.<name>) -> (module, description)
+PROGRAM_SPANS = {
+    "graph.partition": ("core/graph.py",
+                        "partition_graph: the whole host build"),
+    "graph.coo": ("core/graph.py",
+                  "out- and in-edge COO shards (_group_edges)"),
+    "graph.ell": ("core/graph.py",
+                  "the four blocked-ELL builds, side by side"),
+    "graph.ell.<ell>": ("core/graph.py",
+                        "one blocked-ELL build, in its worker thread"),
+    "engine.upload": ("core/api.py",
+                      "device_graph: host arrays to the mesh"),
+    "engine.lower": ("core/api.py",
+                     "CompiledProgram.lower: trace and lower"),
+    "engine.call": ("core/api.py",
+                    "CompiledProgram.__call__: host dispatch of a "
+                    "launch"),
+}
+
+
+def _pattern(name: str) -> str:
+    return "".join(r"[A-Za-z0-9_]+" if part.startswith("<")
+                   else re.escape(part)
+                   for part in re.split(r"(<[a-z]+>)", name))
+
+
+_DECLARED = {where: re.compile("|".join(_pattern(k) for k in table))
+             for where, table in (("device", DEVICE_SCOPES),
+                                  ("host", PROGRAM_SPANS))}
+
+
+@functools.lru_cache(maxsize=None)
+def declared(name: str, where: str = "device") -> bool:
+    """Whether ``name`` is a declared device scope (``where="device"``)
+    or program span (``"host"``), placeholders matching one
+    identifier."""
+    return _DECLARED[where].fullmatch(name) is not None
 
 
 class Registry:
@@ -160,4 +267,15 @@ def instruments_markdown_table() -> str:
              "|------------|------|------|-------------|"]
     for name, (typ, unit, desc) in INSTRUMENTS.items():
         lines.append(f"| `{name}` | {typ} | {unit} | {desc} |")
+    return "\n".join(lines)
+
+
+def scopes_markdown_table() -> str:
+    """Device scopes and program spans as one markdown table."""
+    lines = ["| name | where | module | description |",
+             "|------|-------|--------|-------------|"]
+    for where, table in (("device scope", DEVICE_SCOPES),
+                         ("host span", PROGRAM_SPANS)):
+        for name, (module, desc) in table.items():
+            lines.append(f"| `{name}` | {where} | `{module}` | {desc} |")
     return "\n".join(lines)

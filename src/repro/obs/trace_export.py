@@ -1,19 +1,13 @@
 """Chrome trace-event (Perfetto-loadable) JSON export + schema check.
 
-``chrome_trace`` turns recorder spans/events (and optionally engine
-telemetry) into the Trace Event Format dict ``chrome://tracing`` and
-https://ui.perfetto.dev load directly:
+``chrome_trace`` turns recorder spans/events into the Trace Event
+Format dict ``chrome://tracing`` and https://ui.perfetto.dev load
+directly:
 
   * the SERVER is pid 1 with one track (tid) per declared component
     (``registry.COMPONENTS`` order), so admission/validate/demux nest
     on the "server" track while batch formation and device launches
     read on their own lanes;
-  * ENGINE telemetry is pid 2 with one track per PART — a run's
-    measured wall-time is splayed uniformly over its rounds and the
-    resulting ``engine_round`` spans are emitted on every part's track
-    (each part executes every BSP round; per-part skew is not
-    observable from the host), with the halt scalar and probe values
-    in ``args``;
   * span kinds declared ``complete`` export as "X" events; kinds
     declared ``async`` (query / device / coalesce_wait — they overlap
     on their track) export as "b"/"e" pairs keyed by the recorder
@@ -21,6 +15,8 @@ https://ui.perfetto.dev load directly:
 
 Timestamps are microseconds relative to the earliest stamp in the
 trace (Chrome wants µs; perf_counter's epoch is arbitrary anyway).
+The engine's rounds are not exported here: their device time is in a
+``jax.profiler`` trace, named by device scope (``obs/scopes.py``).
 
 ``validate_chrome_trace`` is the schema gate the CI ``obs`` lane and
 the export tests run: required fields per event shape, matched and
@@ -38,7 +34,6 @@ import pathlib
 from repro.obs.registry import COMPONENTS, SPAN_KINDS
 
 _PID_SERVE = 1
-_PID_ENGINE = 2
 _COMPONENT_TID = {name: i for i, name in enumerate(COMPONENTS)}
 
 
@@ -49,14 +44,9 @@ def _meta(pid: int, name: str, tid: int = 0, thread: str | None = None):
     return ev
 
 
-def chrome_trace(spans=(), events=(), engine=()) -> dict:
-    """Build the trace dict.
-
-    ``spans`` / ``events`` come from ``SpanRecorder.spans()`` /
-    ``.events()``.  ``engine`` is an iterable of ``(label, telemetry,
-    parts)`` with ``telemetry`` a ``RunTelemetry``; each run's rounds
-    are laid end to end after the previous run's on every part track.
-    """
+def chrome_trace(spans=(), events=()) -> dict:
+    """Build the trace dict from ``SpanRecorder.spans()`` /
+    ``.events()``."""
     spans = list(spans)
     events = list(events)
     stamps = [s.t0 for s in spans] + [e.t for e in events]
@@ -90,32 +80,6 @@ def chrome_trace(spans=(), events=(), engine=()) -> dict:
         out.append({"ph": "i", "s": "t", "name": ev.kind,
                     "cat": ev.component, "pid": _PID_SERVE, "tid": tid,
                     "ts": us(ev.t), "args": dict(ev.args)})
-
-    engine = list(engine)
-    if engine:
-        out.append(_meta(_PID_ENGINE, "repro-engine"))
-        parts_max = max(parts for _, _, parts in engine)
-        for part in range(parts_max):
-            out.append(_meta(_PID_ENGINE, "", part,
-                             thread=f"part{part}"))
-        cursor = 0.0
-        for label, tel, parts in engine:
-            rounds = tel.series.rounds
-            total_us = max(tel.wall_s, 1e-6) * 1e6
-            dur = total_us / max(rounds, 1)
-            for r in range(rounds):
-                row = tel.series.rows[r]
-                args = {"run": label, "round": r,
-                        "halt": float(row[1])}
-                for name in tel.series.probe_names:
-                    args[name] = float(tel.series.probe(name)[r])
-                for part in range(parts):
-                    out.append({"ph": "X", "name": "engine_round",
-                                "cat": "engine", "pid": _PID_ENGINE,
-                                "tid": part,
-                                "ts": round(cursor + r * dur, 3),
-                                "dur": round(dur, 3), "args": args})
-            cursor += total_us
     out.sort(key=lambda e: (e["ph"] == "M" and -1, e["ts"]))
     return {"traceEvents": out, "displayTimeUnit": "ms"}
 

@@ -252,23 +252,6 @@ def test_chrome_trace_export_shapes():
     assert b_ids == {3, 4, 5}               # async pairs keyed by seq
 
 
-def test_chrome_trace_engine_tracks():
-    arr = np.zeros((3, 3), np.float32)
-    arr[:, 0] = 1.0
-    arr[2, 1] = 1.0
-    arr[:, 2] = [4, 2, 0]
-    tel = RunTelemetry(series=PhaseSeries.from_array(arr, ("frontier",)),
-                       wall_s=0.012)
-    trace = chrome_trace(engine=[("bfs_fast", tel, 2)])
-    counts = validate_chrome_trace(trace)
-    assert counts["X"] == 3 * 2             # rounds x parts
-    rounds = [e for e in trace["traceEvents"]
-              if e.get("name") == "engine_round"]
-    assert {e["pid"] for e in rounds} == {2}
-    assert {e["tid"] for e in rounds} == {0, 1}
-    assert rounds[0]["args"]["frontier"] == 4.0
-
-
 def test_validator_rejects_malformed_traces():
     def bad(evs):
         with pytest.raises(ValueError):
