@@ -56,8 +56,8 @@ INT_INF = jnp.int32(2 ** 30)
 
 def bc_forward_program(shards, max_levels: int = 64) -> SuperstepProgram:
     """Phase 1: level-synchronous BFS counting shortest paths."""
-    n, n_local = shards.n, shards.n_local
-    ell_dst = shards.ell("ell_dst")
+    n_local = shards.n_local
+    ell_in, ell_dst = shards.ell("ell_in"), shards.ell("ell_dst")
 
     def init(g, root):
         lo = jax.lax.axis_index(AXIS) * n_local
@@ -69,10 +69,8 @@ def bc_forward_program(shards, max_levels: int = 64) -> SuperstepProgram:
 
     def step(g, state):
         dist, sigma, frontier, level, _ = state
-        srcl, dst = g["out_src_local"], g["out_dst_global"]
-        active = frontier[srcl] & (dst < n)
-        acc = localops.scatter_combine(
-            g, ell_dst, jnp.where(active, sigma[srcl], 0.0), "add",
+        acc = localops.push_combine(
+            g, ell_in, ell_dst, jnp.where(frontier, sigma, 0.0), "add",
             identity=jnp.float32(0.0))
         recv = exchange_sum(acc)                    # (n_local,) f32
         newly = (recv > 0) & (dist == INT_INF)

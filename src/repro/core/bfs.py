@@ -17,12 +17,13 @@ Paper mapping (SS4.1):
     traffic at all).
 
 The per-level LOCAL edge work routes through ``core/localops.py``: the
-push-combine is ``scatter_combine`` over the blocked-ELL ``ell_dst``
-structure and owner-side parent derivation is ``frontier_pull`` over
-``ell_in`` (a dense blocked-ELL gather; the Pallas BFS-pull kernel only
-under ``REPRO_LOCALOPS=kernel``) - no serialized scatters on any
-backend.  The push candidate exchange is the packed-uint32
-``exchange_or`` of ``core/partitioned.py``.
+push-combine is ``push_combine`` (a gather through the blocked-ELL
+``ell_in`` structure at parts=1, through ``ell_dst`` at parts>1) and
+owner-side parent derivation is ``frontier_pull`` over ``ell_in`` (a
+dense blocked-ELL gather; the Pallas BFS-pull kernel only under
+``REPRO_LOCALOPS=kernel``) - no serialized scatters on any backend.
+The push candidate exchange is the packed-uint32 ``exchange_or`` of
+``core/partitioned.py``.
 
 Both are expressed as :class:`~repro.core.superstep.SuperstepProgram`
 factories (``init / step / halt / outputs`` over per-shard arrays); the
@@ -59,15 +60,12 @@ def _derive_parents(g, ell_in, gf_packed, unvisited):
     return new_mask, prop
 
 
-def _bsp_level(g, ell_dst, n, n_local, parents, frontier):
+def _bsp_level(g, ell_in, ell_dst, n_local, parents, frontier):
     """One BSP level: full (n,) parent-proposal exchange via a2a MIN."""
     lo = jax.lax.axis_index(AXIS) * n_local
-    srcl = g["out_src_local"]
-    dst = g["out_dst_global"]
-    active = frontier[srcl] & (dst < n)
-    src_g = (srcl + lo).astype(jnp.int32)
-    prop = localops.scatter_combine(
-        g, ell_dst, jnp.where(active, src_g, INT_INF), "min",
+    gid = jnp.arange(n_local, dtype=jnp.int32) + lo
+    prop = localops.push_combine(
+        g, ell_in, ell_dst, jnp.where(frontier, gid, INT_INF), "min",
         identity=INT_INF)
     # exchange: every partition contributes proposals for every vertex
     mine = exchange_min_int(prop)                  # (n_local,)
@@ -91,16 +89,13 @@ def _fast_level(g, ell_in, parents, gf_packed):
     return parents, gf_next, count
 
 
-def _fast_level_push(g, ell_in, ell_dst, n, parents,
-                     frontier_local, gf_packed):
+def _fast_level_push(g, ell_in, ell_dst, parents, frontier_local,
+                     gf_packed):
     """Push variant: OR-combine candidate bits from active out-edges,
     then ship ONLY the packed candidate bitmap (n/32 u32) through the
     packed ``exchange_or``."""
-    srcl = g["out_src_local"]
-    dst = g["out_dst_global"]
-    active = frontier_local[srcl] & (dst < n)
-    cand = localops.scatter_combine(g, ell_dst, active, "or",
-                                    identity=False)        # (n,) bool
+    cand = localops.push_combine(g, ell_in, ell_dst, frontier_local, "or",
+                                 identity=False)           # (n,) bool
     # activation bits for my slice; derive parents by pulling in-edges
     unvisited = parents == INT_INF
     activated = exchange_or(cand) & unvisited
@@ -146,8 +141,8 @@ def bfs_bsp_program(shards, max_levels: int = 64) -> SuperstepProgram:
     proposes nothing), so the program is safe under the driver's
     fixed-trip ``static_iters`` scan.
     """
-    n, n_local = shards.n, shards.n_local
-    ell_dst = shards.ell("ell_dst")
+    n_local = shards.n_local
+    ell_in, ell_dst = shards.ell("ell_in"), shards.ell("ell_dst")
 
     def init(g, root):
         parents0, frontier0 = _seed_state(root, n_local)
@@ -155,7 +150,7 @@ def bfs_bsp_program(shards, max_levels: int = 64) -> SuperstepProgram:
 
     def step(g, state):
         parents, frontier, _ = state
-        return _bsp_level(g, ell_dst, n, n_local, parents, frontier)
+        return _bsp_level(g, ell_in, ell_dst, n_local, parents, frontier)
 
     return SuperstepProgram(
         name="bfs", variant="bsp", inputs=("root",),
@@ -200,8 +195,8 @@ def bfs_fast_program(shards, max_levels: int = 64,
 
         @device_scope("bfs.push")
         def push(_):
-            p, f, g2, c = _fast_level_push(g, ell_in, ell_dst, n,
-                                           parents, frontier, gf)
+            p, f, g2, c = _fast_level_push(g, ell_in, ell_dst, parents,
+                                           frontier, gf)
             return p, f, g2, c
 
         @device_scope("bfs.pull")
@@ -257,10 +252,8 @@ def bfs_async_program(shards, max_levels: int = 64,
         return level0, at_root
 
     def relax(g, level, frontier):
-        srcl = g["out_src_local"]
-        active = frontier[srcl] & (g["out_dst_global"] < n)
-        return localops.scatter_combine(
-            g, ell_dst, jnp.where(active, level[srcl] + 1, INT_INF),
+        return localops.push_combine(
+            g, ell_in, ell_dst, jnp.where(frontier, level + 1, INT_INF),
             "min", identity=INT_INF)
 
     def outputs(g, level):

@@ -36,7 +36,7 @@ def cc_program(shards, max_rounds: int = 64,
     """
     n, n_local = shards.n, shards.n_local
     n_orig = shards.n_orig
-    ell_dst = shards.ell("ell_dst")
+    ell_in, ell_dst = shards.ell("ell_in"), shards.ell("ell_dst")
     ell_src = shards.ell("ell_src")
 
     def init(g, *inputs):
@@ -53,17 +53,13 @@ def cc_program(shards, max_rounds: int = 64,
 
     def step(g, state):
         labels, _ = state
-        srcl = g["out_src_local"]
-        dst = g["out_dst_global"]
-        valid = dst < n
         in_src = g["in_src_global"]
         in_dstl = g["in_dst_local"]
         in_valid = in_src < n
         # propose my label to out-neighbors (push direction); the local
         # MIN-combine is a blocked-ELL gather+reduce (localops)
-        prop = localops.scatter_combine(
-            g, ell_dst, jnp.where(valid, labels[srcl], INT_INF), "min",
-            identity=INT_INF)
+        prop = localops.push_combine(g, ell_in, ell_dst, labels, "min",
+                                     identity=INT_INF)
         mine = exchange_min_int(prop)
         new_labels = jnp.minimum(labels, mine)
         # pull direction: adopt min label of in-neighbors (needs their
@@ -116,14 +112,11 @@ def cc_async_program(shards, max_rounds: int = 64,
         return gid, jnp.ones((n_local,), bool)
 
     def relax(g, labels, frontier):
-        srcl = g["out_src_local"]
-        valid = g["out_dst_global"] < n
         in_dstl = g["in_dst_local"]
         in_valid = g["in_src_global"] < n
-        push = localops.scatter_combine(
-            g, shards.ell("ell_dst"),
-            jnp.where(frontier[srcl] & valid, labels[srcl], INT_INF),
-            "min", identity=INT_INF)
+        push = localops.push_combine(
+            g, shards.ell("ell_in"), shards.ell("ell_dst"),
+            jnp.where(frontier, labels, INT_INF), "min", identity=INT_INF)
         pull = localops.scatter_combine(
             g, shards.ell("ell_src"),
             jnp.where(frontier[in_dstl] & in_valid, labels[in_dstl],

@@ -4,19 +4,25 @@
 distributed graph algorithm's per-superstep *work bundle* from its
 exchange machinery; ``core/partitioned.py`` owns the exchanges, and this
 module owns the work bundle.  Every program hot loop routes through one
-of three primitives:
+of five primitives:
 
   ``spmv_pull(g, ell, x)``
       y[v] = sum over in-neighbors u of v of x[u]  (PageRank pull).
   ``frontier_pull(g, ell, bits, unvisited)``
       min-id in-neighbor of v present in the packed frontier bitmap, or
       INT_INF (owner-side BFS parent derivation).
-  ``scatter_combine(g, ell, vals, op, identity=...)``
-      combine per-edge values into a per-row accumulator with
-      op in {add, min, max, or} - the generalized push combine.
   ``pull_min_eq(g, ell, xg, target)``
       min-id in-neighbor u of v with xg[u] == target[v] - the
       level-keyed frontier_pull (bfs/async parent derivation).
+  ``scatter_combine(g, ell, vals, op, identity=...)``
+      combine per-edge values into a per-row accumulator with
+      op in {add, min, max, or} - the generalized edge combine.
+  ``push_combine(g, ell_in, ell_dst, x, op, identity=...)``
+      combine a per-source vertex field over each destination's arcs
+      into a length-n accumulator (PageRank, BFS and betweenness push).
+      At parts=1 it gathers ``x`` once per ``ell_in`` slot, the same
+      loop as ``spmv_pull``; at parts>1 it gathers ``x`` into out-edge
+      order and combines through ``ell_dst``.
 
 Each primitive has THREE implementations, selected at trace time:
 
@@ -56,7 +62,9 @@ by shared indices would put the lane axis minor, which a TPU pads to
 Each primitive runs inside its device scope ``localops.<primitive>``,
 each bucket's gather inside ``<ell name>.b<i>`` and the final
 inverse-permutation gather inside ``reorder`` (``obs/scopes.py``), so a
-profiler trace of the ell path can be read per primitive and bucket.
+profiler trace of the ell path can be read per primitive and bucket;
+under ``localops.push_combine`` the bucket names show the route taken
+(``ell_in.b<i>`` at parts=1, ``ell_dst.b<i>`` at parts>1).
 """
 
 from __future__ import annotations
@@ -189,6 +197,47 @@ def _reorder(outs, inv):
         return _take(jnp.concatenate(outs), inv)
 
 
+# reduce a slot-major (k, rows) gather over its slots
+_REDUCERS = {
+    "add": lambda a: a.sum(axis=0),
+    "min": lambda a: a.min(axis=0),
+    "max": lambda a: a.max(axis=0),
+    "or": lambda a: a.any(axis=0),
+}
+
+
+def _check_op(op: str) -> None:
+    if op not in _REDUCERS:
+        raise ValueError(f"combine op {op!r} not in {tuple(_REDUCERS)}")
+
+
+def _combine_slots(g: dict, ell: EllMeta, x, op: str, identity, mode: str):
+    """``op``-combine of ``x[slot]`` over each row's slots of ``ell``, in
+    row order: the ell path of every gather-and-reduce primitive.
+
+    ``x`` is what the slots index: a vertex field for the neighbor-id
+    structure ``ell_in``, per-edge values for an edge-position one.  The
+    sentinel (n or E, one past ``x``) reads an appended ``identity``
+    slot.  f32 sums take the SpMV kernel in the kernel mode."""
+    xk = jnp.concatenate([x, jnp.full((1,), identity, x.dtype)])
+    kernel_add = (op == "add" and x.dtype == jnp.float32
+                  and _use_pallas(mode))
+    outs = []
+    for scope, _, rows, k, blk in _buckets(ell, g[f"{ell.name}_idx"]):
+        with scope:
+            if k == 0:
+                outs.append(jnp.full((rows,), identity, x.dtype))
+            elif kernel_add:
+                from repro.kernels.spmv.kernel import spmv_ell
+                vmask = (blk != ell.sentinel).astype(jnp.float32)
+                outs.append(spmv_ell(blk, vmask, xk, row_block=128,
+                                     interpret=_interpret()))
+            else:
+                # (k, rows) slot-major, rows minor
+                outs.append(_REDUCERS[op](_take(xk, blk.T)))
+    return _reorder(outs, g[f"{ell.name}_inv"])
+
+
 # ---------------------------------------------------------------------------
 # spmv_pull
 # ---------------------------------------------------------------------------
@@ -210,27 +259,7 @@ def spmv_pull(g: dict, ell: EllMeta, x, *, mode: str | None = None):
         gathered = jnp.where(valid, x[jnp.where(valid, src, 0)], 0.0)
         return jnp.zeros((ell.n_rows,), jnp.float32).at[dstl].add(
             gathered, mode="drop")
-
-    idx = g[f"{ell.name}_idx"]
-    inv = g[f"{ell.name}_inv"]
-    xk = jnp.concatenate([x, jnp.zeros((1,), jnp.float32)])  # sentinel slot
-    use_pallas = _use_pallas(mode)
-    outs = []
-    for scope, _, rows, k, blk in _buckets(ell, idx):
-        with scope:
-            if k == 0:
-                outs.append(jnp.zeros((rows,), jnp.float32))
-                continue
-            if use_pallas:
-                from repro.kernels.spmv.kernel import spmv_ell
-                vmask = (blk != ell.sentinel).astype(jnp.float32)
-                outs.append(spmv_ell(blk, vmask, xk,
-                                     row_block=128, interpret=_interpret()))
-            else:
-                cols = blk.T                        # (k, rows), rows minor
-                outs.append(jnp.where(cols != ell.sentinel, _take(xk, cols),
-                                      0.0).sum(axis=0))
-    return _reorder(outs, inv)
+    return _combine_slots(g, ell, x, "add", jnp.float32(0.0), mode)
 
 
 # ---------------------------------------------------------------------------
@@ -340,13 +369,19 @@ def pull_min_eq(g: dict, ell: EllMeta, xg, target, *,
 # scatter_combine
 # ---------------------------------------------------------------------------
 
-# reduce a slot-major (k, rows) gather over its slots
-_REDUCERS = {
-    "add": lambda a: a.sum(axis=0),
-    "min": lambda a: a.min(axis=0),
-    "max": lambda a: a.max(axis=0),
-    "or": lambda a: a.any(axis=0),
-}
+def _scatter_combine(g, ell, vals, op, identity, mode):
+    if mode == "ref" or not _has_ell(g, ell):
+        key_name, may_drop = _COO_KEY[ell.name]
+        key = g[key_name]
+        size = ell.n_rows + (1 if may_drop else 0)
+        if op == "or":  # bool OR as the uint8 scatter-max idiom
+            acc = jnp.zeros((size,), jnp.uint8).at[key].max(
+                vals.astype(jnp.uint8))
+            return acc[:ell.n_rows] > 0
+        acc = jnp.full((size,), identity, vals.dtype)
+        acc = getattr(acc.at[key], op)(vals)
+        return acc[:ell.n_rows]
+    return _combine_slots(g, ell, vals, op, identity, mode)
 
 
 @device_scope("localops.scatter_combine")
@@ -361,40 +396,40 @@ def scatter_combine(g: dict, ell: EllMeta, vals, op: str, *, identity,
     back as ``identity`` — callers pass the same sentinel the old
     scatter idiom initialized its accumulator with (0, INT_INF, ...).
     """
-    mode = mode or get_mode()
-    if op not in _REDUCERS:
-        raise ValueError(f"scatter_combine op {op!r} not in "
-                         f"{tuple(_REDUCERS)}")
-    if mode == "ref" or not _has_ell(g, ell):
-        key_name, may_drop = _COO_KEY[ell.name]
-        key = g[key_name]
-        size = ell.n_rows + (1 if may_drop else 0)
-        if op == "or":  # bool OR as the uint8 scatter-max idiom
-            acc = jnp.zeros((size,), jnp.uint8).at[key].max(
-                vals.astype(jnp.uint8))
-            return acc[:ell.n_rows] > 0
-        acc = jnp.full((size,), identity, vals.dtype)
-        acc = getattr(acc.at[key], op)(vals)
-        return acc[:ell.n_rows]
+    _check_op(op)
+    return _scatter_combine(g, ell, vals, op, identity, mode or get_mode())
 
-    idx = g[f"{ell.name}_idx"]
-    inv = g[f"{ell.name}_inv"]
-    # sentinel E indexes the pad slot, which carries the identity
-    vpad = jnp.concatenate(
-        [vals, jnp.full((1,), identity, vals.dtype)], axis=-1)
-    kernel_add = (op == "add" and vals.dtype == jnp.float32
-                  and _use_pallas(mode))
-    outs = []
-    for scope, _, rows, k, blk in _buckets(ell, idx):
-        with scope:
-            if k == 0:
-                outs.append(jnp.full((rows,), identity, vals.dtype))
-                continue
-            if kernel_add:
-                from repro.kernels.spmv.kernel import spmv_ell
-                vmask = (blk != ell.sentinel).astype(jnp.float32)
-                outs.append(spmv_ell(blk, vmask, vpad, row_block=128,
-                                     interpret=_interpret()))
-            else:
-                outs.append(_REDUCERS[op](_take(vpad, blk.T)))
-    return _reorder(outs, inv)
+
+# ---------------------------------------------------------------------------
+# push_combine
+# ---------------------------------------------------------------------------
+
+@device_scope("localops.push_combine")
+def push_combine(g: dict, ell_in: EllMeta, ell_dst: EllMeta, x, op: str, *,
+                 identity, mode: str | None = None):
+    """Combine a per-SOURCE vertex field over each destination's arcs.
+
+    ``x`` is the partition's (n_local,) field, already masked: a source
+    that sends nothing carries ``identity`` (``where(frontier, sigma,
+    0)``).  Returns the (n,) accumulator ``acc[v] = op over arcs u->v of
+    x[u]`` that ``scatter_combine(g, ell_dst, where(valid, x[srcl],
+    identity), op)`` returns, by one of two routes, chosen from the
+    partition count:
+
+    * parts=1 (``ell_in`` has ``ell_dst``'s n rows): ``ell_in`` holds
+      the same (destination, source) slots as ``ell_dst`` composed with
+      ``out_src_local``, in the same order, so ``x`` is gathered once
+      per ``ell_in`` slot (scopes ``ell_in.b<i>``);
+    * parts>1, no ELL arrays, or the ref mode: ``x`` is gathered into
+      out-edge order and combined through ``ell_dst`` (scopes
+      ``ell_dst.b<i>``), a second gather per arc that an ``ell_in`` of
+      local rows cannot save.
+    """
+    mode = mode or get_mode()
+    _check_op(op)
+    if (mode != "ref" and ell_in.n_rows == ell_dst.n_rows
+            and _has_ell(g, ell_in)):
+        return _combine_slots(g, ell_in, x, op, identity, mode)
+    vals = jnp.where(g["out_dst_global"] < ell_dst.n_rows,
+                     x[g["out_src_local"]], identity)
+    return _scatter_combine(g, ell_dst, vals, op, identity, mode)

@@ -23,9 +23,10 @@ loop.
 
 The local segment-sum is the SpMV hot spot; it routes through
 ``core/localops.py`` (``spmv_pull`` over the blocked-ELL in-neighbor
-lists for the pull variant, ``scatter_combine`` over ``ell_dst`` for the
-push variant): a dense per-bucket gather + row-sum on every backend
-(the Pallas SpMV kernel only under ``REPRO_LOCALOPS=kernel``) - the
+lists for the pull variant, ``push_combine`` for the push variants:
+over the same ``ell_in`` lists at parts=1, through ``ell_dst`` at
+parts>1): a dense per-bucket gather + row-sum on every backend (the
+Pallas SpMV kernel only under ``REPRO_LOCALOPS=kernel``) - the
 serialized COO scatter survives only as the ``REPRO_LOCALOPS=ref``
 debug path.
 """
@@ -131,7 +132,7 @@ def pagerank_fast_program(shards, iters: int = 50,
     fewer rounds (the dynamic-graph warm-restart win).
     """
     n, n_local, n_orig = shards.n, shards.n_local, shards.n_orig
-    ell_dst = shards.ell("ell_dst")
+    ell_in, ell_dst = shards.ell("ell_in"), shards.ell("ell_dst")
     base = (1.0 - ALPHA) / n_orig
 
     def init(g, *inputs):
@@ -149,15 +150,11 @@ def pagerank_fast_program(shards, iters: int = 50,
 
     def step(g, state):
         rank, resid, err_prev, it = state
-        srcl = g["out_src_local"]                   # (E,) local
-        dst = g["out_dst_global"]                   # (E,) sentinel n
-        valid = dst < n
         contrib = _local_contrib(rank, g["out_degree"])
         # local segment-sum into a length-n accumulator (SpMV push);
         # localops routes it to a dense blocked-ELL gather + row-sum.
-        acc = localops.scatter_combine(
-            g, ell_dst, jnp.where(valid, contrib[srcl], 0.0), "add",
-            identity=jnp.float32(0.0))
+        acc = localops.push_combine(g, ell_in, ell_dst, contrib, "add",
+                                    identity=jnp.float32(0.0))
 
         @device_scope("pagerank.compressed")
         def compressed(_):
@@ -236,7 +233,7 @@ def pagerank_async_program(shards, iters: int = 64, tol: float = 1e-6,
     the NumPy model of the recurrence).
     """
     n, n_local, n_orig = shards.n, shards.n_local, shards.n_orig
-    ell_dst = shards.ell("ell_dst")
+    ell_in, ell_dst = shards.ell("ell_in"), shards.ell("ell_dst")
     base = (1.0 - ALPHA) / n_orig
     if staleness < 1:
         raise ValueError(f"staleness must be >= 1, got {staleness}")
@@ -244,12 +241,9 @@ def pagerank_async_program(shards, iters: int = 64, tol: float = 1e-6,
     def _contrib_acc(g, rank):
         """(n,) push accumulator with the OWN slice zeroed for shipping:
         the exchange must deliver purely-remote contributions."""
-        srcl = g["out_src_local"]
-        valid = g["out_dst_global"] < n
         contrib = _local_contrib(rank, g["out_degree"])
-        acc = localops.scatter_combine(
-            g, ell_dst, jnp.where(valid, contrib[srcl], 0.0), "add",
-            identity=jnp.float32(0.0))
+        acc = localops.push_combine(g, ell_in, ell_dst, contrib, "add",
+                                    identity=jnp.float32(0.0))
         lo = jax.lax.axis_index(AXIS) * n_local
         own = jax.lax.dynamic_slice_in_dim(acc, lo, n_local)
         ship = jax.lax.dynamic_update_slice_in_dim(

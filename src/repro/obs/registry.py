@@ -149,6 +149,10 @@ DEVICE_SCOPES = {
                              "value"),
     "localops.scatter_combine": ("core/localops.py",
                                  "combine per-edge values per row"),
+    "localops.push_combine": ("core/localops.py",
+                              "combine a source-vertex field per "
+                              "destination (ell_in at parts=1, ell_dst "
+                              "at parts>1)"),
     "<ell>.b<i>": ("core/localops.py",
                    "gather and row reduction of bucket i of an ELL "
                    "structure"),

@@ -7,6 +7,8 @@ interpret mode), plus layout/property guards:
     random graphs when hypothesis is installed);
   * whole programs produce identical results under ``layout="ell"`` and
     ``layout="coo"`` (the escape-hatch path compiles the same math);
+  * ``push_combine`` gives what the scatter path gives on either of its
+    routes (``ell_in`` at parts=1, ``ell_dst`` at parts>1), batched too;
   * REPRO_LOCALOPS mode resolution and the set_mode override;
   * the batched gather keeps every lane's answer, and the host build's
     fast stable sort builds the same graph as ``np.argsort``.
@@ -16,6 +18,8 @@ are exercised here directly on per-partition graph dicts - the
 multi-partition exchange behaviour is covered by the oracle-conformance
 gate, which runs the ELL path by default.
 """
+
+import functools
 
 import numpy as np
 import pytest
@@ -143,6 +147,96 @@ def test_scatter_combine_out_rows(graph, rng):
                 identity=0.0, mode=mode))
             np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4,
                                        err_msg=f"p={p} mode={mode}")
+
+
+# ---------------------------------------------------------------------------
+# push_combine: one gather per ell_in slot at parts=1, ell_dst at parts>1
+# ---------------------------------------------------------------------------
+
+PUSH_IDENTITY = {"add": np.float32(0.0), "min": np.int32(INT_INF),
+                 "max": np.int32(-1), "or": np.bool_(False)}
+
+
+@functools.lru_cache(maxsize=None)
+def _push_graph(family, parts):
+    """A conformance graph plus self-loops and duplicate arcs; its arc
+    count is no multiple of 128, so every partition has padding edges."""
+    edges, n = oracle.family_edges(family, 300, 3)
+    extra = np.array([[0, 0], [5, 5], [1, 2], [1, 2], [1, 2], [7, 3]])
+    edges = np.concatenate([edges, extra]).astype(edges.dtype)
+    return partition_graph(edges, n, parts=parts)
+
+
+def _push_field(rng, op, shape):
+    """A per-source field with about half its sources masked out."""
+    if op == "or":
+        return rng.integers(0, 2, shape) > 0
+    if op == "add":   # whole numbers: every summation order is exact
+        x = rng.integers(0, 8, shape).astype(np.float32)
+    else:
+        x = rng.integers(0, 10 ** 6, shape).astype(np.int32)
+    return np.where(rng.random(shape) < 0.5, x, PUSH_IDENTITY[op])
+
+
+def _push_ref(garr, g, x, op):
+    """What the scatter path gives: ``x`` gathered into out-edge order,
+    combined through ``ell_dst`` by the COO scatter."""
+    srcl = np.asarray(garr["out_src_local"])
+    valid = np.asarray(garr["out_dst_global"]) < g.n
+    vals = np.where(valid, x[srcl], PUSH_IDENTITY[op])
+    return np.asarray(localops.scatter_combine(
+        garr, g.ell_meta["ell_dst"], jnp.asarray(vals), op,
+        identity=PUSH_IDENTITY[op], mode="ref"))
+
+
+@pytest.mark.parametrize("op", ["add", "min", "max", "or"])
+@pytest.mark.parametrize("parts", [1, 2, 4])
+@pytest.mark.parametrize("family", ["urand", "rmat"])
+def test_push_combine_matches_the_scatter_path(rng, family, parts, op):
+    g = _push_graph(family, parts)
+    ell_in, ell_dst = g.ell_meta["ell_in"], g.ell_meta["ell_dst"]
+    for p, garr in enumerate(_shard_dicts(g)):
+        x = _push_field(rng, op, g.n_local)
+        want = _push_ref(garr, g, x, op)
+        for mode in MODES:
+            got = np.asarray(localops.push_combine(
+                garr, ell_in, ell_dst, jnp.asarray(x), op,
+                identity=PUSH_IDENTITY[op], mode=mode))
+            np.testing.assert_array_equal(got, want,
+                                          err_msg=f"p={p} mode={mode}")
+        if parts == 1 and op == "add":
+            # same slots in the same order as ell_dst: the f32 sums of
+            # arbitrary values are bit-identical to the edge-order path
+            xf = rng.normal(size=g.n_local).astype(np.float32)
+            srcl = np.asarray(garr["out_src_local"])
+            valid = np.asarray(garr["out_dst_global"]) < g.n
+            edge_order = localops.scatter_combine(
+                garr, ell_dst, jnp.asarray(np.where(valid, xf[srcl], 0.0)),
+                "add", identity=np.float32(0.0))
+            np.testing.assert_array_equal(
+                np.asarray(localops.push_combine(
+                    garr, ell_in, ell_dst, jnp.asarray(xf), "add",
+                    identity=np.float32(0.0))),
+                np.asarray(edge_order))
+
+
+@pytest.mark.parametrize("op", ["add", "min", "max", "or"])
+@pytest.mark.parametrize("parts", [1, 2])
+def test_push_combine_batched_matches_per_lane(rng, parts, op):
+    """A vmapped (lanes, n_local) field gives each lane's own answer."""
+    g = _push_graph("rmat", parts)
+    ell_in, ell_dst = g.ell_meta["ell_in"], g.ell_meta["ell_dst"]
+    garr = _shard_dicts(g)[-1]
+    xs = _push_field(rng, op, (3, g.n_local))
+
+    def one(x):
+        return localops.push_combine(garr, ell_in, ell_dst, x, op,
+                                     identity=PUSH_IDENTITY[op])
+
+    got = np.asarray(jax.jit(jax.vmap(one))(jnp.asarray(xs)))
+    for b, x in enumerate(xs):
+        np.testing.assert_array_equal(got[b], _push_ref(garr, g, x, op),
+                                      err_msg=f"lane {b}")
 
 
 # ---------------------------------------------------------------------------

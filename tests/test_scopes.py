@@ -1,9 +1,11 @@
 """Names on the engine's work (``repro/obs/scopes.py``, ``obs/spans.py``):
 device scopes read back from compiled HLO by ``op_scopes``, their
-coverage of the compiled bfs/fast and pagerank/fast loops, the refusal
+coverage of the compiled bfs/fast and pagerank/fast loops, the route
+``localops.push_combine`` takes at one and two partitions, the refusal
 of undeclared names, the program's host spans and the serve spans on
 the profiler's clock, and the docs table of both."""
 
+import json
 import os
 import re
 
@@ -12,7 +14,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from conftest import REPO
+from conftest import REPO, run_with_devices
 from repro.core import GraphEngine, partition_graph
 from repro.graphs import urand_edges
 from repro.launch.mesh import make_graph_mesh
@@ -150,8 +152,9 @@ def test_every_loop_instruction_maps_to_a_declared_scope(eng, algo):
                 assert any(p.startswith("localops.") for p in parts[:i]), \
                     (name, path)
     # every non-empty bucket of the structure the program reads is named
-    prim, ell = (("localops.frontier_pull", "ell_in") if algo == "bfs"
-                 else ("localops.scatter_combine", "ell_dst"))
+    prim = ("localops.frontier_pull" if algo == "bfs"
+            else "localops.push_combine")
+    ell = "ell_in"
     want = {f"{prim}/{ell}.b{i}"
             for i, (_, k) in enumerate(eng.g.ell(ell).buckets) if k}
     have = {"/".join(p for p in path.split("/")
@@ -160,6 +163,58 @@ def test_every_loop_instruction_maps_to_a_declared_scope(eng, algo):
     assert want <= have
     # the executable compile() returned is kept for the profiler reader
     assert compiled_scopes()["jit_fn"] == scopes
+
+
+GATHER = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*\w+\[([\d,]*)\]"
+                    r"\S*\s+gather\(", re.M)
+
+
+def _gathers(text):
+    """``{instruction: output dims}`` of every gather in an HLO text."""
+    return {m.group(1): [int(d) for d in m.group(2).split(",") if d]
+            for m in GATHER.finditer(text)}
+
+
+def _push_routes(scopes):
+    """The ELL structures read under ``localops.push_combine``."""
+    return {m.group(1) for path in scopes.values()
+            for m in [re.search(r"localops\.push_combine/(ell_\w+)\.b\d+",
+                                path)] if m}
+
+
+@pytest.mark.parametrize("algo", ["bfs", "pagerank"])
+def test_push_combine_gathers_once_per_ell_in_slot_at_one_partition(eng,
+                                                                    algo):
+    text = eng.program(algo, "fast").aot().as_text()
+    scopes = op_scopes(text)
+    assert _push_routes(scopes) == {"ell_in"}
+    # no per-arc gather into edge order is left outside the local ops
+    per_arc = [name for name, dims in _gathers(text).items()
+               if eng.g.e_max in dims
+               and "localops." not in scopes[name]]
+    assert not per_arc, [(n, scopes[n]) for n in per_arc]
+
+
+_PARTS2_CODE = """
+import json
+from repro.core import GraphEngine, partition_graph
+from repro.graphs import urand_edges
+from repro.launch.mesh import make_graph_mesh
+from repro.obs import op_scopes
+n = 1 << 9
+g = partition_graph(urand_edges(n, 16 * n, seed=9), n, parts=2)
+eng = GraphEngine(g, make_graph_mesh(2))
+print(json.dumps({algo: sorted(set(op_scopes(
+    eng.program(algo, "fast").aot().as_text()).values()))
+    for algo in ("bfs", "pagerank")}))
+"""
+
+
+def test_push_combine_goes_through_ell_dst_at_two_partitions():
+    out = json.loads(run_with_devices(_PARTS2_CODE, devices=2)
+                     .strip().splitlines()[-1])
+    for algo, paths in out.items():
+        assert _push_routes(dict(enumerate(paths))) == {"ell_dst"}, algo
 
 
 def test_undeclared_names_are_refused():
